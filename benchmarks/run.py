@@ -19,6 +19,9 @@ def main() -> None:
                          "throughput suite also writes BENCH_throughput.json)")
     args, _ = ap.parse_known_args()
 
+    from benchmarks import common  # noqa: F401  (puts src/ on sys.path)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_case_study, bench_continuous_batching,
                             bench_fault_tolerance, bench_kernels,
                             bench_kv_compression, bench_network_effect,

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build
 from repro.serving.engine import DecodeEngine, PrefillEngine
 from repro.serving.gateway import (DONE, Gateway, SchedulerConfig,
@@ -70,6 +71,7 @@ def main():
                          "finish)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_reduced(args.arch)
     api = build(cfg)
     params = api.init(jax.random.PRNGKey(0))
@@ -99,7 +101,7 @@ def main():
                      prefill_chunk_tokens=args.chunk_tokens,
                      max_prefill_batch=args.max_prefill_batch,
                      decode_chunk_steps=args.decode_chunk_steps),
-                 compress=not args.no_compress, backend="ref")
+                 compress=not args.no_compress, backend="auto")
 
     print("warming up jit caches...")
     warmup_gateway(gw, cfg.vocab_size, prompt_lens=(16, 24, 32))
@@ -222,9 +224,8 @@ def main():
         print("events:", gw.events[:5])
     n_done = s["states"].get(DONE, 0)
     if n_done < args.requests:
-        print(f"WARNING: only {n_done}/{args.requests} requests finished")
-        if args.chaos:
-            sys.exit(1)
+        print(f"FAILED: only {n_done}/{args.requests} requests finished")
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -18,7 +18,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -63,9 +62,9 @@ def pipeline_apply(fn: Callable, params_stacked, x, *, mesh,
         return outbuf.reshape((B,) + x_full.shape[1:])
 
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(spec_p, P()),
-                     out_specs=P(), check_rep=False)(params_stacked, x)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_p, P()),
+                         out_specs=P(), check_vma=False)(params_stacked, x)
 
 
 def pipeline_stage_specs(mesh, params_stacked, axis: str = "pod"):

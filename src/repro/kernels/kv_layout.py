@@ -18,9 +18,9 @@ HERE and only here:
 * :func:`pick_group` — the ONE group-selection rule (largest candidate
   dividing the span; groups never straddle token positions when the span
   is ``Hkv * hd``);
-* :func:`pack_nibbles` / :func:`unpack_nibbles` — the low-nibble-first
-  two-per-byte int4 packing (element ``2j`` in the low nibble of byte
-  ``j``, ``2j+1`` in the high nibble).
+* :func:`split_even_odd` / :func:`pack_nibbles` / :func:`unpack_nibbles`
+  — the low-nibble-first two-per-byte int4 packing (element ``2j`` in the
+  low nibble of byte ``j``, ``2j+1`` in the high nibble).
 
 This module is dependency-light (jnp only) so both ``serving/`` and
 ``models/`` can import it without cycles, and the helpers are plain jnp
@@ -45,9 +45,21 @@ def pick_group(span: int) -> int:
     return next((g for g in GROUPS if span % g == 0), 0)
 
 
+def split_even_odd(x):
+    """``(..., G)`` -> the ``(..., G//2)`` planes that :func:`pack_nibbles`
+    puts in the low (even elements) and high (odd elements) nibbles.
+
+    A lane-strided split, so it runs in XLA ahead of the quantization
+    kernel: Mosaic refuses the ``(n, G) -> (n, G//2, 2)`` reshape."""
+    return x[..., 0::2], x[..., 1::2]
+
+
 def pack_nibbles(even, odd):
     """Pack two int4 planes into one uint8 plane, LOW NIBBLE FIRST:
-    ``even`` lands in bits 0-3, ``odd`` in bits 4-7."""
+    ``even`` lands in bits 0-3, ``odd`` in bits 4-7. The arithmetic runs
+    in int32: Mosaic has no float<->uint8 casts, only int32<->uint8."""
+    even = even.astype(jnp.int32)
+    odd = odd.astype(jnp.int32)
     return (even | (odd << 4)).astype(jnp.uint8)
 
 
@@ -55,8 +67,9 @@ def unpack_nibbles(packed):
     """Inverse of :func:`pack_nibbles`: uint8 plane -> (low, high) f32
     planes. Callers interleave with :func:`interleave_nibbles` (or keep
     the planes separate when the layout wants them that way)."""
-    lo = (packed & 0xF).astype(jnp.float32)
-    hi = (packed >> 4).astype(jnp.float32)
+    w = packed.astype(jnp.int32)
+    lo = (w & 0xF).astype(jnp.float32)
+    hi = (w >> 4).astype(jnp.float32)
     return lo, hi
 
 

@@ -6,7 +6,9 @@ These kernels implement the pack/unpack hot loop: per-group (last axis)
 min/max affine quantization, two nibbles packed per uint8.
 
 Tiling: rows are blocked (block_n x G tiles in VMEM); G (the quantization
-group, e.g. head_dim) sits in the lane dimension.
+group, e.g. head_dim) sits in the lane dimension. The quantizer reads the
+group as its even and odd halves, split in XLA before the launch
+(``kv_layout.split_even_odd``), so the kernel body only packs.
 """
 from __future__ import annotations
 
@@ -15,23 +17,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 # nibble order is THE layout contract shared with the wire and the paged
 # cache — it lives in kv_layout (lint rule R005), and the helpers are
 # plain jnp expressions so they trace inside the kernel bodies
-from repro.kernels.kv_layout import interleave_nibbles, pack_nibbles
+from repro.kernels.kv_layout import (interleave_nibbles, pack_nibbles,
+                                     split_even_odd)
 
 
-def _quant_kernel(x_ref, q_ref, scale_ref, zero_ref):
-    x = x_ref[...].astype(jnp.float32)               # (bn, G)
-    mn = x.min(axis=-1, keepdims=True)
-    mx = x.max(axis=-1, keepdims=True)
+def _quant_kernel(xe_ref, xo_ref, q_ref, scale_ref, zero_ref):
+    xe = xe_ref[...].astype(jnp.float32)             # (bn, G//2) even
+    xo = xo_ref[...].astype(jnp.float32)             # (bn, G//2) odd
+    mn = jnp.minimum(xe.min(axis=-1, keepdims=True),
+                     xo.min(axis=-1, keepdims=True))
+    mx = jnp.maximum(xe.max(axis=-1, keepdims=True),
+                     xo.max(axis=-1, keepdims=True))
     scale = jnp.maximum(mx - mn, 1e-8) / 15.0
-    q = jnp.clip(jnp.round((x - mn) / scale), 0, 15).astype(jnp.uint8)
-    bn, G = q.shape
-    q2 = q.reshape(bn, G // 2, 2)
-    q_ref[...] = pack_nibbles(q2[..., 0], q2[..., 1])
+
+    def q(x):
+        return jnp.clip(jnp.round((x - mn) / scale), 0, 15).astype(jnp.int32)
+
+    q_ref[...] = pack_nibbles(q(xe), q(xo))
     scale_ref[...] = scale
     zero_ref[...] = mn
 
@@ -52,10 +58,11 @@ def kv_quant(x, *, block_n=256, interpret=False):
     N, G = x.shape
     assert G % 2 == 0
     block_n = min(block_n, N)
+    half = pl.BlockSpec((block_n, G // 2), lambda i: (i, 0))
     return pl.pallas_call(
         _quant_kernel,
         grid=(pl.cdiv(N, block_n),),
-        in_specs=[pl.BlockSpec((block_n, G), lambda i: (i, 0))],
+        in_specs=[half, half],
         out_specs=[
             pl.BlockSpec((block_n, G // 2), lambda i: (i, 0)),
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
@@ -67,7 +74,7 @@ def kv_quant(x, *, block_n=256, interpret=False):
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x)
+    )(*split_even_odd(x))
 
 
 def kv_dequant(packed, scale, zero, *, out_dtype=jnp.bfloat16, block_n=256,

@@ -3,7 +3,8 @@
 ``backend="auto"`` picks the Pallas TPU kernel on TPU, the interpreted
 kernel under tests that request it, and the pure-jnp reference otherwise
 (CPU dry-run lowers the jnp path so rooflines reflect XLA:TPU-able HLO, not
-an interpreter artifact).
+an interpreter artifact). A backend that cannot be queried raises: a
+silent fallback would hide the device from the run.
 """
 from __future__ import annotations
 
@@ -21,10 +22,7 @@ from repro.kernels import rmsnorm as _rn
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _mode(backend: str) -> str:

@@ -6,7 +6,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.kv_layout import interleave_nibbles, pack_nibbles
+from repro.kernels.kv_layout import (interleave_nibbles, pack_nibbles,
+                                     split_even_odd)
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0, sm_scale=None):
@@ -89,7 +90,7 @@ def kv_quant_ref(x):
     mx = xf.max(axis=-1, keepdims=True)
     scale = jnp.maximum(mx - mn, 1e-8) / 15.0
     q = jnp.clip(jnp.round((xf - mn) / scale), 0, 15).astype(jnp.uint8)
-    packed = pack_nibbles(q[..., 0::2], q[..., 1::2])
+    packed = pack_nibbles(*split_even_odd(q))
     return packed, scale, mn
 
 

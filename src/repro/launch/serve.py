@@ -16,6 +16,7 @@ the cluster's bandwidth matrix).
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -26,6 +27,7 @@ from repro.core import scheduler
 from repro.core.cluster import make_cluster
 from repro.core.orchestrator import SloSpec
 from repro.core.workload import WORKLOADS, generate
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build
 from repro.serving.engine import DecodeEngine, PrefillEngine
 from repro.serving.gateway import (Gateway, SchedulerConfig, ServeRequest,
@@ -92,6 +94,7 @@ def main():
                          "(default: the other one)")
     args = ap.parse_args()
 
+    use_compile_cache()
     wl = WORKLOADS[args.workload]
     cluster = make_cluster(args.cluster)
     cfg_full = get_config(args.arch)
@@ -133,7 +136,7 @@ def main():
                                decode_kw=paged_kw, scheduler=sched_cfg,
                                profiler=WorkloadProfiler(
                                    in_scale=IN_SCALE, out_scale=OUT_SCALE),
-                               compress=not args.no_compress, backend="ref")
+                               compress=not args.no_compress, backend="auto")
         pres = [h.engine for h in gw.pre]
         decs = [h.engine for h in gw.dec]
     else:
@@ -147,12 +150,12 @@ def main():
         gw = Gateway(pres, decs, transport=transport,
                      orchestration=plan.orchestration,
                      scheduler=sched_cfg,
-                     compress=not args.no_compress, backend="ref")
+                     compress=not args.no_compress, backend="auto")
 
     print("[3/4] serving the request stream (open loop, "
           f"{args.transport} transport)...")
     warmup_engines(pres, decs, cfg.vocab_size,
-                   compress=not args.no_compress, backend="ref",
+                   compress=not args.no_compress, backend="auto",
                    prompt_lens=(16, 32, 48))
     rng = np.random.default_rng(0)
 
@@ -261,6 +264,10 @@ def main():
         for r in st["replicas"]))
     if gw.events:
         print("  events:", gw.events[:5])
+    if s["n_done"] < s["n_submitted"]:
+        print(f"FAILED: only {s['n_done']}/{s['n_submitted']} requests "
+              f"finished", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

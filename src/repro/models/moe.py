@@ -146,7 +146,6 @@ def moe_apply_shard_map(cfg, p, x, *, mesh, expert_axis="model",
     from every peer in its data row, runs the FFN, and reverses the exchange.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     ep = mesh.shape[expert_axis]
     E, k = cfg.moe_num_experts, cfg.moe_top_k
@@ -217,10 +216,10 @@ def moe_apply_shard_map(cfg, p, x, *, mesh, expert_axis="model",
         aux = lax.pmean(aux, tuple(mesh.shape.keys()))
         return y.reshape(xl.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(w_specs["router"], w_specs["wi"], w_specs["wo"], x_spec),
-        out_specs=(x_spec, P()), check_rep=False)
+        out_specs=(x_spec, P()), check_vma=False)
     y, aux = fn(p["router"], p["wi"], p["wo"], x)
     if cfg.moe_shared_expert:
         y = y + layers.mlp_apply(cfg, p["shared"], x)
