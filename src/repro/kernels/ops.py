@@ -72,9 +72,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
 @functools.partial(jax.jit, static_argnames=("backend", "block_n"))
 def kv_quant(x, *, backend="auto", block_n=256):
     m = _mode(backend)
-    if m == "ref":
-        return _ref.kv_quant_ref(x)
-    return _kq.kv_quant(x, block_n=block_n, interpret=(m == "interpret"))
+    with jax.named_scope("kv_quant"):
+        if m == "ref":
+            return _ref.kv_quant_ref(x)
+        return _kq.kv_quant(x, block_n=block_n, interpret=(m == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "block_n", "out_dtype"))

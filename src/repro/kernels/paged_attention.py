@@ -130,6 +130,13 @@ def _kernel_bf16(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         _finalize(o_ref, m_scr, l_scr, acc_scr)
 
 
+def grid_pages(batch: int, table_width: int) -> int:
+    """Page-table entries one launch of ``paged_decode_attention`` covers:
+    its grid is ``(batch, table_width)``, one page a step, whether the
+    page holds live context or not."""
+    return batch * table_width
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
                            page_size, sm_scale=None, interpret=False):
     """q: (B, Hkv, gq, hd); page_table: (B, W) int32 (entry 0 = the trash

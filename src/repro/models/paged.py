@@ -27,7 +27,12 @@ never hands page 0 out.
 ``decode_step_paged`` mirrors ``transformer.decode_step_inplace``: a
 ``fori_loop`` over superblocks, the new token's K/V quantized INLINE
 (bit-identical to ``kv_quant_ref``) and scattered into the slot's tail
-page, attention via ``ops.paged_decode_attention``.
+page, attention via ``ops.paged_decode_attention``. Its device work sits
+under named scopes (operation metadata only): ``decode/weights`` (the
+layer's slice of the stacked weights), ``decode/qkv``,
+``decode/kv_append`` (the tail-page write), ``decode/kv_pages`` (each
+layer's pool handed to the kernel), ``decode/paged_attn`` (the kernel and
+the output projection), ``decode/ffn`` and ``decode/head``.
 """
 from __future__ import annotations
 
@@ -151,61 +156,72 @@ def decode_step_paged(cfg, params, cache, tokens, *, rt=None,
 
     def body(i, carry):
         x, bc = carry
-        blk = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
-            a, i, 0, keepdims=False), params["blocks"])
+        with jax.named_scope("decode/weights"):
+            blk = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False), params["blocks"])
         for s, kind in enumerate(kinds):
             p = blk[f"slot{s}"]
-            h = norm_apply(cfg, p["norm1"], x)
-            q, k, v = layers.attn_qkv(cfg, p["attn"], h, positions)
+            with jax.named_scope("decode/qkv"):
+                h = norm_apply(cfg, p["norm1"], x)
+                q, k, v = layers.attn_qkv(cfg, p["attn"], h, positions)
             slot = bc[f"slot{s}"]
             if int4:
-                # append-path quantization IS the wire/ref kernel math
-                # (kv_quant_ref), so page contents are bit-identical no
-                # matter which path wrote a token
-                kq, ksc, kzp = kv_quant_ref(k[:, 0].reshape(B * ppr, g))
-                vq, vsc, vzp = kv_quant_ref(v[:, 0].reshape(B * ppr, g))
-                rows = tail_off[:, None] * ppr + jnp.arange(ppr)[None]
-                pg = tail_page[:, None]
-                for name, val, width in (("kp", kq, g // 2), ("ks", ksc, 1),
-                                         ("kz", kzp, 1), ("vp", vq, g // 2),
-                                         ("vs", vsc, 1), ("vz", vzp, 1)):
-                    slot[name] = slot[name].at[i, pg, rows].set(
-                        val.reshape(B, ppr, width).astype(slot[name].dtype))
-                kpages = tuple(lax.dynamic_index_in_dim(slot[n], i, 0,
-                                                        keepdims=False)
-                               for n in ("kp", "ks", "kz"))
-                vpages = tuple(lax.dynamic_index_in_dim(slot[n], i, 0,
-                                                        keepdims=False)
-                               for n in ("vp", "vs", "vz"))
+                with jax.named_scope("decode/kv_append"):
+                    # append-path quantization IS the wire/ref kernel math
+                    # (kv_quant_ref), so page contents are bit-identical
+                    # no matter which path wrote a token
+                    kq, ksc, kzp = kv_quant_ref(k[:, 0].reshape(B * ppr, g))
+                    vq, vsc, vzp = kv_quant_ref(v[:, 0].reshape(B * ppr, g))
+                    rows = tail_off[:, None] * ppr + jnp.arange(ppr)[None]
+                    pg = tail_page[:, None]
+                    for name, val, width in (
+                            ("kp", kq, g // 2), ("ks", ksc, 1),
+                            ("kz", kzp, 1), ("vp", vq, g // 2),
+                            ("vs", vsc, 1), ("vz", vzp, 1)):
+                        slot[name] = slot[name].at[i, pg, rows].set(
+                            val.reshape(B, ppr, width).astype(
+                                slot[name].dtype))
+                with jax.named_scope("decode/kv_pages"):
+                    kpages = tuple(lax.dynamic_index_in_dim(
+                        slot[n], i, 0, keepdims=False)
+                        for n in ("kp", "ks", "kz"))
+                    vpages = tuple(lax.dynamic_index_in_dim(
+                        slot[n], i, 0, keepdims=False)
+                        for n in ("vp", "vs", "vz"))
             else:
-                slot["k"] = slot["k"].at[i, tail_page, tail_off].set(
-                    k[:, 0].astype(slot["k"].dtype))
-                slot["v"] = slot["v"].at[i, tail_page, tail_off].set(
-                    v[:, 0].astype(slot["v"].dtype))
-                kpages = lax.dynamic_index_in_dim(slot["k"], i, 0,
-                                                  keepdims=False)
-                vpages = lax.dynamic_index_in_dim(slot["v"], i, 0,
-                                                  keepdims=False)
+                with jax.named_scope("decode/kv_append"):
+                    slot["k"] = slot["k"].at[i, tail_page, tail_off].set(
+                        k[:, 0].astype(slot["k"].dtype))
+                    slot["v"] = slot["v"].at[i, tail_page, tail_off].set(
+                        v[:, 0].astype(slot["v"].dtype))
+                with jax.named_scope("decode/kv_pages"):
+                    kpages = lax.dynamic_index_in_dim(slot["k"], i, 0,
+                                                      keepdims=False)
+                    vpages = lax.dynamic_index_in_dim(slot["v"], i, 0,
+                                                      keepdims=False)
             bc[f"slot{s}"] = slot
-            qr = q[:, 0].reshape(B, Hkv, gq, hd)
-            o = ops.paged_decode_attention(qr, kpages, vpages, pt,
-                                           pos0 + 1, page_size=ps,
-                                           backend=backend)
-            out = dense(p["attn"]["wo"],
-                        o.reshape(B, 1, cfg.q_dim).astype(x.dtype))
-            if cfg.parallel_block and "mlp" in p:
-                x = x + out + layers.mlp_apply(cfg, p["mlp"], h)
-            else:
-                x = x + out
-                if kind.split("+")[1] != "none":
-                    delta, _ = _apply_ffn(cfg, p, kind, x, rt)
-                    x = x + delta
+            with jax.named_scope("decode/paged_attn"):
+                qr = q[:, 0].reshape(B, Hkv, gq, hd)
+                o = ops.paged_decode_attention(qr, kpages, vpages, pt,
+                                               pos0 + 1, page_size=ps,
+                                               backend=backend)
+                out = dense(p["attn"]["wo"],
+                            o.reshape(B, 1, cfg.q_dim).astype(x.dtype))
+            with jax.named_scope("decode/ffn"):
+                if cfg.parallel_block and "mlp" in p:
+                    x = x + out + layers.mlp_apply(cfg, p["mlp"], h)
+                else:
+                    x = x + out
+                    if kind.split("+")[1] != "none":
+                        delta, _ = _apply_ffn(cfg, p, kind, x, rt)
+                        x = x + delta
         return (x, bc)
 
     x, block_cache = lax.fori_loop(0, n_super, body, (x, block_cache))
-    x = norm_apply(cfg, params["final_norm"], x)
-    w = unembed_matrix(cfg, params)
-    logits = (x @ w).astype(jnp.float32)
+    with jax.named_scope("decode/head"):
+        x = norm_apply(cfg, params["final_norm"], x)
+        w = unembed_matrix(cfg, params)
+        logits = (x @ w).astype(jnp.float32)
     new_cache = dict(block_cache)
     new_cache["page_table"] = pt
     new_cache["lengths"] = cache["lengths"] + 1

@@ -69,7 +69,8 @@ def make_decode_chunk(decode_fn: Callable) -> Callable:
             cache, cur, active, n_out = carry
             logits, new_cache = decode_fn(params, cache,
                                           {"tokens": cur[:, None]})
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            with jax.named_scope("decode/head"):
+                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             n_out = n_out + active.astype(jnp.int32)
             new_cache = dict(new_cache)
             new_cache["lengths"] = jnp.where(active, new_cache["lengths"],

@@ -23,9 +23,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import paged_attention
 from repro.models import paged as paged_fmt
 from repro.models import registry
-from repro.serving import kv_transfer, page_pool, protocol
+from repro.serving import kv_transfer, page_pool, protocol, tracing
 from repro.serving.kv_transfer import KVWire
 from repro.serving.page_pool import PagePool, pages_needed
 from repro.serving.prefix_cache import PrefixCache, PrefixMatch
@@ -185,6 +186,14 @@ class PrefillEngine:
         # (batch, suffix_bucket, prefix_bucket) so the jit cache stays
         # log2-bounded in both lengths
         self._suffix_jits: Dict[Tuple[int, int, int], Callable] = {}
+        # device work under the ``prefill`` scope; the jitted programs
+        # keep their names (``jit__lambda``)
+        self._prefill = tracing.scoped("prefill", self.api.prefill)
+        self._suffix = (tracing.scoped("prefill", self.api.prefill_suffix)
+                        if self.api.prefill_suffix is not None else None)
+        # prompt tokens prefilled, and the padded tokens the model ran
+        self.true_tokens = 0
+        self.bucket_tokens = 0
 
     @property
     def supports_suffix(self) -> bool:
@@ -195,14 +204,13 @@ class PrefillEngine:
     def _prefill_fn(self, batch_shape: Tuple[int, int]) -> Callable:
         if batch_shape not in self._jits:
             self._jits[batch_shape] = jax.jit(
-                lambda p, b: self.api.prefill(p, b, max_seq=self.max_seq))
+                lambda p, b: self._prefill(p, b, max_seq=self.max_seq))
         return self._jits[batch_shape]
 
     def _suffix_fn(self, key: Tuple[int, int, int]) -> Callable:
         if key not in self._suffix_jits:
             self._suffix_jits[key] = jax.jit(
-                lambda p, b: self.api.prefill_suffix(
-                    p, b, max_seq=self.max_seq))
+                lambda p, b: self._suffix(p, b, max_seq=self.max_seq))
         return self._suffix_jits[key]
 
     @property
@@ -211,6 +219,12 @@ class PrefillEngine:
 
     def _bucket_of(self, n: int) -> int:
         return min(max(_next_pow2(n), self.min_bucket), self.max_seq)
+
+    def _batch_width(self, n: int) -> int:
+        """Rows of a bucketed call of ``n`` prompts: a pow2 width, so a
+        lone request doesn't pay a full-width forward pass and the jit
+        cache only gains log2(max_batch) variants per length bucket."""
+        return min(_next_pow2(n), self.max_batch)
 
     def run(self, reqs: List[GenRequest], *, compress: bool = True,
             backend: str = "auto") -> List[Tuple[GenRequest, KVWire, int]]:
@@ -221,26 +235,75 @@ class PrefillEngine:
         dequantized prefix KV; the returned wire covers the suffix."""
         if not reqs:
             return []
+        launches = self._launches(reqs)
+        counts = [self._launch_tokens(*launch) for launch in launches]
+        true, padded = (sum(c[i] for c in counts) for i in (0, 1))
+        self.true_tokens += true
+        self.bucket_tokens += padded
+        run_one = {"suffix": self._run_one_suffix_bucket,
+                   "bucket": self._run_one_bucket,
+                   "exact": self._run_one_exact}
+        with tracing.span("prefill", lambda: {
+                "rids": [r.rid for r in reqs], "batch": len(reqs),
+                "true_tokens": true, "bucket_tokens": padded}):
+            out = []
+            for kind, key, group in launches:
+                out.extend(run_one[kind](group, *key, compress=compress,
+                                         backend=backend))
+        return out
+
+    def _launches(self, reqs: List[GenRequest]
+                  ) -> List[Tuple[str, Tuple[int, ...], List[GenRequest]]]:
+        """The model calls ``run`` makes, in order, as ``(kind, key,
+        group)``: ``"suffix"`` prompts grouped by (suffix bucket, prefix
+        bucket), then the rest as ``"bucket"`` (pow2 length bucket) or,
+        for engines that cannot pad, ``"exact"`` (prompt length); bucketed
+        groups split at ``max_batch``."""
         suffix = [r for r in reqs
                   if r.start_pos > 0 and r.prefix_wire is not None]
         sids = {id(r) for r in suffix}
         normal = [r for r in reqs if id(r) not in sids]
+        if suffix and not self.supports_suffix:
+            raise ValueError(
+                "suffix prefill requested but this engine cannot "
+                "slice its state at a position boundary")
+        padded = suffix + (normal if self.bucketed else [])
+        too_long = [r.rid for r in padded if len(r.tokens) > self.max_seq]
+        if too_long:
+            # fail loudly like the exact-length path does, instead of
+            # silently conditioning on a truncated prompt
+            raise ValueError(
+                f"prompt(s) exceed max_seq={self.max_seq}: rids {too_long}")
         out = []
-        if suffix:
-            if not self.supports_suffix:
-                raise ValueError(
-                    "suffix prefill requested but this engine cannot "
-                    "slice its state at a position boundary")
-            out.extend(self._run_suffix(suffix, compress=compress,
-                                        backend=backend))
-        if normal:
-            if self.bucketed:
-                out.extend(self._run_bucketed(normal, compress=compress,
-                                              backend=backend))
-            else:
-                out.extend(self._run_exact(normal, compress=compress,
-                                           backend=backend))
+        for kind, rs in (("suffix", suffix),
+                         ("bucket" if self.bucketed else "exact", normal)):
+            groups: Dict[Tuple[int, ...], List[GenRequest]] = {}
+            for r in rs:
+                if kind == "suffix":
+                    # prefix lengths are page-aligned by construction
+                    key = (self._bucket_of(len(r.tokens) - r.start_pos),
+                           self._bucket_of(r.start_pos))
+                elif kind == "bucket":
+                    key = (self._bucket_of(len(r.tokens)),)
+                else:
+                    key = (len(r.tokens),)
+                groups.setdefault(key, []).append(r)
+            for key, group in groups.items():
+                width = len(group) if kind == "exact" else self.max_batch
+                for lo in range(0, len(group), width):
+                    out.append((kind, key, group[lo:lo + width]))
         return out
+
+    def _launch_tokens(self, kind: str, key: Tuple[int, ...],
+                       group: List[GenRequest]) -> Tuple[int, int]:
+        """(prompt tokens, padded tokens the model computes) of one call:
+        a bucketed call runs a pow2 batch of ``key[0]``-token rows."""
+        if kind == "exact":
+            return key[0] * len(group), key[0] * len(group)
+        start = kind == "suffix"
+        true = sum(len(r.tokens) - (r.start_pos if start else 0)
+                   for r in group)
+        return true, self._batch_width(len(group)) * key[0]
 
     def prefill_chunk(self, jobs: List[PartialPrefill], budget: int, *,
                       compress: bool = True, backend: str = "auto"
@@ -298,53 +361,23 @@ class PrefillEngine:
                     full, backend=backend) if compress else full)
         return jobs
 
-    def _run_exact(self, reqs, *, compress, backend):
-        """Group by exact prompt length (no padding ever enters attention);
-        one jit entry per unique (batch, length) shape."""
-        by_len: Dict[int, List[GenRequest]] = {}
-        for r in reqs:
-            by_len.setdefault(len(r.tokens), []).append(r)
-        out = []
-        for L, group in by_len.items():
-            toks = np.stack([r.tokens for r in group]).astype(np.int32)
-            batch = {"tokens": jnp.asarray(toks)}
-            for key in group[0].extras:
-                batch[key] = jnp.stack(
-                    [jnp.asarray(r.extras[key]) for r in group])
-            logits, cache = self._prefill_fn(toks.shape)(self.params, batch)
-            first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-            wires = kv_transfer.extract_batch(
-                cache, [(i, L) for i in range(len(group))],
-                compress=compress, backend=backend)
-            for i, r in enumerate(group):
-                out.append((r, wires[i], int(first[i])))
-        return out
-
-    def _run_bucketed(self, reqs, *, compress, backend):
-        """Right-pad prompts to power-of-two buckets and a fixed batch
-        width; the whole bucket's KV is quantized in one kernel launch."""
-        too_long = [r.rid for r in reqs if len(r.tokens) > self.max_seq]
-        if too_long:
-            # fail loudly like the exact-length path does, instead of
-            # silently conditioning on a truncated prompt
-            raise ValueError(
-                f"prompt(s) exceed max_seq={self.max_seq}: rids {too_long}")
-        by_bucket: Dict[int, List[GenRequest]] = {}
-        for r in reqs:
-            by_bucket.setdefault(self._bucket_of(len(r.tokens)), []).append(r)
-        out = []
-        for Lb, group in by_bucket.items():
-            for lo in range(0, len(group), self.max_batch):
-                out.extend(self._run_one_bucket(
-                    group[lo:lo + self.max_batch], Lb,
-                    compress=compress, backend=backend))
-        return out
+    def _run_one_exact(self, group, L, *, compress, backend):
+        """Prompts of exactly ``L`` tokens (no padding ever enters
+        attention); one jit entry per unique (batch, length) shape."""
+        toks = np.stack([r.tokens for r in group]).astype(np.int32)
+        batch = {"tokens": jnp.asarray(toks)}
+        for key in group[0].extras:
+            batch[key] = jnp.stack(
+                [jnp.asarray(r.extras[key]) for r in group])
+        logits, cache = self._prefill_fn(toks.shape)(self.params, batch)
+        first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        wires = kv_transfer.extract_batch(
+            cache, [(i, L) for i in range(len(group))],
+            compress=compress, backend=backend)
+        return [(r, wires[i], int(first[i])) for i, r in enumerate(group)]
 
     def _run_one_bucket(self, group, Lb, *, compress, backend):
-        # pow2 batch width: a lone request doesn't pay a full-width
-        # forward pass, and the jit cache only gains log2(max_batch)
-        # variants per length bucket
-        B = min(_next_pow2(len(group)), self.max_batch)
+        B = self._batch_width(len(group))
         lens = [min(len(r.tokens), Lb) for r in group]
         toks = np.zeros((B, Lb), np.int32)
         for i, r in enumerate(group):
@@ -367,29 +400,8 @@ class PrefillEngine:
             compress=compress, backend=backend, pad_to=Lb)
         return [(r, wires[i], int(first[i])) for i, r in enumerate(group)]
 
-    def _run_suffix(self, reqs, *, compress, backend):
-        """Partial-hit path: group by (suffix bucket, prefix bucket) and
-        prefill only the suffix of each prompt against its dequantized
-        prefix KV. Prefix lengths are page-aligned by construction."""
-        too_long = [r.rid for r in reqs if len(r.tokens) > self.max_seq]
-        if too_long:
-            raise ValueError(
-                f"prompt(s) exceed max_seq={self.max_seq}: rids {too_long}")
-        by_key: Dict[Tuple[int, int], List[GenRequest]] = {}
-        for r in reqs:
-            Lb = self._bucket_of(len(r.tokens) - r.start_pos)
-            Pb = self._bucket_of(r.start_pos)
-            by_key.setdefault((Lb, Pb), []).append(r)
-        out = []
-        for (Lb, Pb), group in by_key.items():
-            for lo in range(0, len(group), self.max_batch):
-                out.extend(self._run_one_suffix_bucket(
-                    group[lo:lo + self.max_batch], Lb, Pb,
-                    compress=compress, backend=backend))
-        return out
-
     def _run_one_suffix_bucket(self, group, Lb, Pb, *, compress, backend):
-        B = min(_next_pow2(len(group)), self.max_batch)
+        B = self._batch_width(len(group))
         slens = [min(len(r.tokens) - r.start_pos, Lb) for r in group]
         toks = np.zeros((B, Lb), np.int32)
         for i, r in enumerate(group):
@@ -508,6 +520,11 @@ class DecodeEngine:
         # host-sync accounting (benchmarks read these)
         self.host_syncs = 0
         self.steps_run = 0
+        # decode chunks run, and the page-table entries the paged kernel
+        # read live vs. walked, summed over the chunks' steps
+        self.decode_chunks = 0
+        self.decode_live_pages = 0
+        self.decode_grid_pages = 0
 
     # -- slot management ----------------------------------------------------
 
@@ -572,10 +589,12 @@ class DecodeEngine:
                     check_wire_alignment(it.wire, self.cfg,
                                          context=f"admit MIGRATED "
                                                  f"rid={it.req.rid}")
-        if self.paged:
-            n = self._admit_paged(items, backend=backend)
-        else:
-            n = self._admit_dense(items, backend=backend)
+        with tracing.span("admit",
+                          lambda: {"rids": [it.req.rid for it in items]}):
+            if self.paged:
+                n = self._admit_paged(items, backend=backend)
+            else:
+                n = self._admit_dense(items, backend=backend)
         return AdmissionBatch(items[n:])
 
     def _admit_dense(self, items: List[AdmissionItem], *, backend) -> int:
@@ -859,13 +878,33 @@ class DecodeEngine:
         if self.active == 0:
             return []
         n = n_steps or self.chunk_size
+        live = [r for r in self.slots if r is not None]
+        pages = {}
+        if self.paged:
+            # pages holding the live slots' context at the chunk's start
+            # (the last emitted token's K/V is written by the chunk), and
+            # the page-table entries each launch of the kernel walks
+            ctx = [len(r.tokens) + len(r.out_tokens) - 1 for r in live]
+            pages = {"live_pages": sum(-(-c // self.page_size) for c in ctx),
+                     "grid_pages": paged_attention.grid_pages(
+                         self.max_slots, self.table_w)}
+            self.decode_live_pages += pages["live_pages"] * n
+            self.decode_grid_pages += pages["grid_pages"] * n
+        self.decode_chunks += 1
+        with tracing.span("decode_chunk", lambda: {
+                "rids": [r.rid for r in live], "live_slots": len(live),
+                "steps": n, **pages}):
+            return self._step_chunk(n)
+
+    def _step_chunk(self, n: int) -> List[GenRequest]:
         toks_d, valid_d, self.cache, st = self._chunk(
             self.params, self.cache, self._host_state(),
             n_steps=n, eos_id=self.eos_id, max_seq=self.max_seq)
         # the single device->host hop for this chunk
-        toks, valid, cur, still_active, lengths = jax.device_get(
-            (toks_d, valid_d, st["cur"], st["active"],
-             self.cache["lengths"]))
+        with tracing.span("readback"):
+            toks, valid, cur, still_active, lengths = jax.device_get(
+                (toks_d, valid_d, st["cur"], st["active"],
+                 self.cache["lengths"]))
         self.host_syncs += 1
         self.steps_run += n
         self.cur_token = np.array(cur)   # writable copy (admit mutates it)
@@ -880,20 +919,26 @@ class DecodeEngine:
                 self.slots[i] = None
                 freed.append(i)
         if freed:
-            # release the slots' cache lengths like step_reference does —
-            # the scan freezes lengths for inactive slots, so without this
-            # a finished slot would keep its old extent until re-admission
-            self.cache["lengths"] = \
-                self.cache["lengths"].at[jnp.asarray(freed)].set(0)
-            if self.paged:
-                # finished chains are donated to the prefix index before
-                # the slot's references go back to the pool; the table
-                # row points back at the trash page
-                for req, i in zip(finished, freed):
-                    self._retire_slot(i, req, int(lengths[i]))
-                self.cache["page_table"] = \
-                    self.cache["page_table"].at[jnp.asarray(freed)].set(0)
+            with tracing.span("release",
+                              lambda: {"rids": [r.rid for r in finished]}):
+                self._release_finished(finished, freed, lengths)
         return finished
+
+    def _release_finished(self, finished: List[GenRequest],
+                          freed: List[int], lengths: np.ndarray):
+        # release the slots' cache lengths like step_reference does — the
+        # scan freezes lengths for inactive slots, so without this a
+        # finished slot would keep its old extent until re-admission
+        self.cache["lengths"] = \
+            self.cache["lengths"].at[jnp.asarray(freed)].set(0)
+        if self.paged:
+            # finished chains are donated to the prefix index before the
+            # slot's references go back to the pool; the table row points
+            # back at the trash page
+            for req, i in zip(finished, freed):
+                self._retire_slot(i, req, int(lengths[i]))
+            self.cache["page_table"] = \
+                self.cache["page_table"].at[jnp.asarray(freed)].set(0)
 
     def step_reference(self) -> List[GenRequest]:
         """SEED PATH (kept for A/B benchmarks + equivalence tests): one
@@ -958,6 +1003,9 @@ class DecodeEngine:
                                  if p not in referenced)
         st["cow_copies"] = self.cow_copies
         st["prefix_admits"] = self.prefix_admits
+        st["decode_chunks"] = self.decode_chunks
+        st["decode_live_pages"] = self.decode_live_pages
+        st["decode_grid_pages"] = self.decode_grid_pages
         if self.prefix_cache is not None:
             for k, v in self.prefix_cache.stats().items():
                 st[f"prefix_{k}"] = v

@@ -56,6 +56,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import scheduler as sched
 from repro.core.orchestrator import Orchestration, SloSpec
+from repro.serving import tracing
 from repro.serving.engine import (ADMIT_CHUNKED, ADMIT_FRESH,
                                   ADMIT_MIGRATED, ADMIT_PREFIX_HIT,
                                   AdmissionBatch, AdmissionItem,
@@ -276,6 +277,13 @@ class PrefillClient(Protocol):
         accumulated chunk wires live here)."""
         ...
 
+    def prefill_stats(self) -> Optional[Dict[str, int]]:
+        """``true_tokens``, the prompt tokens prefilled, and
+        ``bucket_tokens``, the padded tokens the model ran for them; None
+        for a replica not in the prefill phase. The observability seam
+        ``Gateway.stats()`` sums (rule R003)."""
+        ...
+
 
 class DecodeClient(Protocol):
     """Everything the gateway needs from a decode replica."""
@@ -346,6 +354,10 @@ class LocalPrefillClient:
 
     def supports_suffix(self) -> bool:
         return self.engine.supports_suffix
+
+    def prefill_stats(self):
+        return {"true_tokens": self.engine.true_tokens,
+                "bucket_tokens": self.engine.bucket_tokens}
 
     def jit_cache_size(self) -> int:
         return self.engine.jit_cache_size
@@ -453,6 +465,13 @@ class LocalReplicaClient:
     def supports_suffix(self) -> bool:
         return (self.replica.phase == "prefill"
                 and self.replica.engine.supports_suffix)
+
+    def prefill_stats(self):
+        if self.replica.phase != "prefill":
+            return None
+        eng = self.replica.engine
+        return {"true_tokens": eng.true_tokens,
+                "bucket_tokens": eng.bucket_tokens}
 
     # -- DecodeClient --------------------------------------------------------
 
@@ -975,6 +994,10 @@ class Gateway:
 
         ``max_prefill_batch`` is a DEPRECATED one-PR override of
         ``scheduler.max_prefill_batch``."""
+        with tracing.span("pump"):
+            return self._pump(max_prefill_batch)
+
+    def _pump(self, max_prefill_batch: Optional[int]) -> int:
         if self.chaos is not None:
             self.chaos.tick(self.clock())
         if self._pending_failover:
@@ -989,11 +1012,14 @@ class Gateway:
         batch_cap = (max_prefill_batch if max_prefill_batch is not None
                      else self.scheduler.max_prefill_batch)
         if self.queue or self._chunks:
-            self._dispatch_round(batch_cap, now)
+            with tracing.span("dispatch"):
+                self._dispatch_round(batch_cap, now)
         # 2. drain KV transfers whose wires have arrived into decode slots
         #    (prefill-side queueing: wires wait here if the target has no
         #    free slot, cf. Appendix E)
-        self._drain_transfers()
+        if self.transfer_queue:
+            with tracing.span("drain_transfers"):
+                self._drain_transfers()
         # 3. advance every decode replica one chunk of steps; stream every
         #    newly emitted token to its handle
         return self._step_decodes()
@@ -1249,24 +1275,26 @@ class Gateway:
             t0 = self.clock()
             ns = self.scheduler.decode_chunk_steps
             try:
-                finished = (handle.client.step(n_steps=ns) if ns
-                            else handle.client.step())
+                with tracing.span("decode", lambda: {"replica": handle.idx}):
+                    finished = (handle.client.step(n_steps=ns) if ns
+                                else handle.client.step())
             except ReplicaCrashError as e:
                 self._confirm_dead(handle, str(e))
                 continue
             t1 = self.clock()
             if handle.client.active or finished:
                 self._track(handle, t1 - t0, t1)
-            for req in handle.client.resident():
-                self._sync_tokens(self._by_req[id(req)], t1)
-            for req in finished:
-                h = self._by_req[id(req)]
-                self._sync_tokens(h, t1)
-                h._transition(DONE, t1)
-                self.profiler.record(len(req.tokens), len(req.out_tokens),
-                                     t1)
-                self._finish(h)
-                n_done += 1
+            with tracing.span("deliver", lambda: {"replica": handle.idx}):
+                for req in handle.client.resident():
+                    self._sync_tokens(self._by_req[id(req)], t1)
+                for req in finished:
+                    h = self._by_req[id(req)]
+                    self._sync_tokens(h, t1)
+                    h._transition(DONE, t1)
+                    self.profiler.record(len(req.tokens),
+                                         len(req.out_tokens), t1)
+                    self._finish(h)
+                    n_done += 1
         return n_done
 
     def _finish(self, h: RequestHandle):
@@ -1639,8 +1667,10 @@ class Gateway:
 
     def stats(self) -> Dict[str, object]:
         """Operational counters for drivers/dashboards: queue depths,
-        recovery counters, aggregated page-pool stats (incl.
-        ``alloc_failures``), and per-replica detector status."""
+        recovery counters, prefill tokens true and padded
+        (``counters``), aggregated page-pool stats (incl.
+        ``alloc_failures`` and the decode kernel's live and walked page
+        counts), and per-replica detector status."""
         pool: Optional[Dict[str, float]] = None
         for d in self.dec:
             # through the client seam (rule R003): an RPC client serves
@@ -1656,9 +1686,18 @@ class Gateway:
                          "zero_copy_inserts", "reencoded_inserts",
                          "shares", "unshares", "shared_pages", "cow_copies",
                          "prefix_admits", "prefix_entries", "prefix_pages",
-                         "prefix_evictions", "prefix_donations")}
+                         "prefix_evictions", "prefix_donations",
+                         "decode_chunks", "decode_live_pages",
+                         "decode_grid_pages")}
             for k in pool:
                 pool[k] += st.get(k, 0)
+        prefill = {"true_tokens": 0, "bucket_tokens": 0}
+        for p in self.pre:
+            # guarded like page_stats: a minimal client may serve neither
+            ps = getattr(p.client, "prefill_stats", None)
+            st = (ps() if callable(ps) else None) or {}
+            for k in prefill:
+                prefill[k] += st.get(k, 0)
         probes = self.n_prefix_hits + self.n_prefix_partial \
             + self.n_prefix_miss
         prefix = {
@@ -1683,7 +1722,9 @@ class Gateway:
                          "preemptions": self.n_preemptions,
                          "chunk_ticks": self.n_chunk_ticks,
                          "chunked_prefills": self.n_chunked_prefills,
-                         "failed": self.n_failed},
+                         "failed": self.n_failed,
+                         "prefill_true_tokens": prefill["true_tokens"],
+                         "prefill_bucket_tokens": prefill["bucket_tokens"]},
             "page_pool": pool,
             "prefix": prefix,
             "replicas": [{"phase": h.phase, "idx": h.idx,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.serving import tracing
 # the ONE layout contract (candidate groups + selection rule) — local
 # copies of these constants are forbidden (lint rule R005)
 from repro.kernels.kv_layout import GROUPS as _GROUPS
@@ -206,6 +207,12 @@ def extract_batch(cache, requests: Sequence[Tuple[int, int]], *,
     request is sliced to the same padded length so the WHOLE batch is one
     kernel launch; the packed rows are then trimmed to each request's true
     length without leaving the device."""
+    with tracing.span("wire", lambda: {"requests": len(requests),
+                                       "pad_to": pad_to or 0}):
+        return _extract_batch(cache, requests, compress, backend, pad_to)
+
+
+def _extract_batch(cache, requests, compress, backend, pad_to):
     group = None
     if pad_to is not None:
         # padded-path precondition: groups must not straddle positions, so
@@ -252,6 +259,11 @@ def compress_wire(wire: KVWire, *, backend: str = "auto") -> KVWire:
     spliced chunked-prefill wire is bit-identical to a one-shot
     bucketed extraction of the same cache values, and paged decode
     engines can scatter its rows zero-copy."""
+    with tracing.span("wire", lambda: {"requests": 1, "pad_to": 0}):
+        return _compress_wire(wire, backend)
+
+
+def _compress_wire(wire: KVWire, backend: str) -> KVWire:
     slots: Dict[str, Dict[str, WireTensor]] = {}
     jobs: List[Tuple[str, str, jnp.ndarray]] = []
     for name, slot_wire in wire.slots.items():

@@ -291,6 +291,15 @@ def insert_wires(cache, cfg, items: Sequence[Tuple], *,
     decode's tail appends touch pages here. Updates page-table rows and
     lengths. Returns (cache, n_zero_copy, n_reencoded) — the counters
     feed the bench's zero-dequant claim."""
+    import jax
+
+    from repro.serving import tracing
+    with tracing.span("page_insert", lambda: {"wires": len(items)}), \
+            jax.named_scope("page_insert"):
+        return _insert_wires(cache, cfg, items, backend)
+
+
+def _insert_wires(cache, cfg, items: Sequence[Tuple], backend: str):
     import jax.numpy as jnp
     import numpy as np
 

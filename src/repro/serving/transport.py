@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
                     Tuple)
 
+from repro.serving import tracing
 from repro.serving.kv_transfer import KVWire, wire_bytes_uncompressed
 
 
@@ -73,11 +74,14 @@ class InProcessTransport:
 
     def send(self, wire: KVWire, src_replica: int, dst_replica: int,
              *, now: Optional[float] = None) -> TransferTicket:
-        if self.materialize:
-            wire.materialize()
-        self.transfers += 1
-        return TransferTicket(wire, now if now is not None else self.clock(),
-                              clock=self.clock)
+        with tracing.span("transfer", lambda: {"src": src_replica,
+                                               "dst": dst_replica}):
+            if self.materialize:
+                wire.materialize()
+            self.transfers += 1
+            return TransferTicket(wire,
+                                  now if now is not None else self.clock(),
+                                  clock=self.clock)
 
     def send_decode(self, wire: KVWire, src_dec: int, dst_dec: int,
                     *, now: Optional[float] = None) -> TransferTicket:
