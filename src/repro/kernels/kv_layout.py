@@ -22,12 +22,13 @@ HERE and only here:
   — the low-nibble-first two-per-byte int4 packing (element ``2j`` in the
   low nibble of byte ``j``, ``2j+1`` in the high nibble).
 
-This module is dependency-light (jnp only) so both ``serving/`` and
+This module is dependency-light (jax only) so both ``serving/`` and
 ``models/`` can import it without cycles, and the helpers are plain jnp
 expressions so Pallas kernel bodies can call them while tracing.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Candidate quantization group widths (lane dim of the pallas kernel).
@@ -79,3 +80,21 @@ def interleave_nibbles(packed):
     lo, hi = unpack_nibbles(packed)
     return jnp.stack([lo, hi], axis=-1).reshape(
         packed.shape[:-1] + (packed.shape[-1] * 2,))
+
+
+def interleave_nibbles_mxu(packed):
+    """:func:`interleave_nibbles` of a 2-D ``(n, G//2)`` plane as two
+    matmuls, for Pallas TPU kernel bodies: Mosaic builds the stack-and-
+    reshape interleave out of 2-lane tiles, many times the block's VMEM.
+    Exact: the nibbles (0..15) and the 0/1 placement matrices are exact in
+    bf16, and each output element sums one product in f32."""
+    lo, hi = unpack_nibbles(packed)
+    half = packed.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+
+    def place(plane, odd):
+        return jnp.dot(plane.astype(jnp.bfloat16),
+                       (col == 2 * row + odd).astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    return place(lo, 0) + place(hi, 1)

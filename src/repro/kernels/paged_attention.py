@@ -18,11 +18,17 @@ groups per token. Row ``t*ppr + r`` holds token ``t``'s r-th group, so a
 page row-range is exactly a token range — the same row order the wire's
 flattened ``(L*len*ppr, g)`` quantization produces.
 
-Grid = (B, n_pages_per_seq) with the page axis innermost; the page table
-and per-sequence valid lengths arrive as scalar-prefetch operands, so each
-step's BlockSpec index map gathers the right page from HBM and fully
-masked pages are skipped. Online-softmax state sits in VMEM scratch
-(same scheme as ``decode_attention.py``).
+The grid is ragged: one step per block of ``ppb`` pages that holds
+context, summed over the slots (at least one a slot), so a launch's cost
+follows the live context and not the table width. Its length and the
+step -> (slot, block, pages) schedule are computed from ``kv_len`` ahead
+of the call and arrive as scalar-prefetch operands; each step's
+BlockSpecs gather the block's pages from the pool. A page past a slot's
+context repeats the page its operand held the step before, so the
+pipeline copies nothing for it: a launch copies each slot's pages that
+hold context, once. Online-softmax state sits in VMEM scratch (same
+scheme as ``decode_attention.py``). Both residencies share one body; they
+differ only in how a block is loaded (dequantize, or cast).
 """
 from __future__ import annotations
 
@@ -37,104 +43,142 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import kv_layout
 
 NEG_INF = -1e30
+# keys a step aims for: one MXU pass wide
+BLOCK_TOKENS = 128
+# the pipeline's two buffers of a step's page blocks; half of a v5e's
+# default scoped VMEM
+BLOCK_VMEM_BYTES = 8 << 20
 
 
-def _dequant_rows(packed, scale, zero):
-    """(R, G//2) u8 + (R, 1) scale/zero -> (R, G) f32. Nibble order comes
-    from the shared layout contract (``kernels/kv_layout.py``, rule R005):
-    element 2j in the low nibble of byte j, 2j+1 high."""
-    return kv_layout.interleave_nibbles(packed) * scale + zero
+def _load_int4(pages, *, tokens, Hkv, hd):
+    """Dequantize a block: its pages' rows stacked, (ppb*ps*ppr, g//2) u8
+    and (ppb*ps*ppr, 1) scale/zero -> (ppb*ps*ppr, g) f32, token-major, so
+    each token's flattened Hkv*hd slice -> (tokens, Hkv, hd). Nibble order
+    comes from the shared layout contract (``kernels/kv_layout.py``, rule
+    R005): element 2j in the low nibble of byte j, 2j+1 high."""
+    def dequant(packed, scale, zero):
+        rows = lambda refs: jnp.concatenate([r[0] for r in refs])
+        x = kv_layout.interleave_nibbles_mxu(rows(packed))
+        return (x * rows(scale) + rows(zero)).reshape(tokens, Hkv, hd)
+    kp, ks, kz, vp, vs, vz = pages
+    return dequant(kp, ks, kz), dequant(vp, vs, vz)
 
 
-def _accumulate(q, k, v, *, start, kv_len, sm_scale, m_scr, l_scr, acc_scr):
-    """One page of online softmax. q (Hkv, gq, hd); k/v (ps, Hkv, hd)."""
-    kT = k.transpose(1, 0, 2)                       # (Hkv, ps, hd)
-    vT = v.transpose(1, 0, 2)
-    s = jax.lax.dot_general(q, kT, (((2,), (2,)), ((0,), (0,))),
+def _load_bf16(pages, *, tokens, Hkv, hd):
+    return tuple(jnp.concatenate([r[0] for r in refs]).astype(jnp.float32)
+                 for refs in pages)
+
+
+def _limit(kv_len, page_size, W):
+    """Valid keys of a slot: no more than its table holds."""
+    return jnp.minimum(kv_len, W * page_size)
+
+
+def _n_blocks(limit, page_size, ppb):
+    """Steps a slot takes: its blocks that hold context, one at least."""
+    return jnp.maximum(pl.cdiv(pl.cdiv(limit, page_size), ppb), 1)
+
+
+def _kernel(slot_ref, blk_ref, page_ref, len_ref, q_ref, *refs, load, n_ops,
+            sm_scale, page_size, ppb, W):
+    pages = [refs[n * ppb:(n + 1) * ppb] for n in range(n_ops)]
+    o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
+    t = pl.program_id(0)
+    b, j = slot_ref[t], blk_ref[t]
+    limit = _limit(len_ref[b], page_size, W)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q = q_ref[0].astype(jnp.float32)            # (Hkv, gq, hd)
+    Hkv, gq, hd = q.shape
+    tokens = ppb * page_size
+    k, v = load(pages, tokens=tokens, Hkv=Hkv, hd=hd)
+    start = j * tokens
+    # a page past the context holds whatever its operand held before:
+    # zero its values so nothing reaches the sum through p == 0
+    tok = start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    v = jnp.where(tok < limit, v, 0.0)
+    s = jax.lax.dot_general(q, k.transpose(1, 0, 2),
+                            (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * sm_scale
     pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(pos < kv_len, s, NEG_INF)         # (Hkv, gq, ps)
+    s = jnp.where(pos < limit, s, NEG_INF)      # (Hkv, gq, tokens)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
     p = jnp.exp(s - m_new[..., None])
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
     acc_scr[...] = acc_scr[...] * corr[..., None] + jax.lax.dot_general(
-        p, vT, (((2,), (1,)), ((0,), (0,))),
+        p, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
-
-def _finalize(o_ref, m_scr, l_scr, acc_scr):
-    o_ref[0] = (acc_scr[...]
-                / jnp.maximum(l_scr[...], 1e-30)[..., None]).astype(
-                    o_ref.dtype)
-
-
-def _kernel_int4(pt_ref, len_ref, q_ref, kp_ref, ks_ref, kz_ref,
-                 vp_ref, vs_ref, vz_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                 sm_scale, page_size, n_pages, Hkv, hd):
-    b = pl.program_id(0)
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    kv_len = len_ref[b]
-    start = pi * page_size
-
-    @pl.when(start < kv_len)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        # dequant fused here: rows are token-major, so (ps*ppr, g) -> each
-        # token's flattened Hkv*hd slice -> (ps, Hkv, hd)
-        k = _dequant_rows(kp_ref[0], ks_ref[0], kz_ref[0]).reshape(
-            page_size, Hkv, hd)
-        v = _dequant_rows(vp_ref[0], vs_ref[0], vz_ref[0]).reshape(
-            page_size, Hkv, hd)
-        _accumulate(q, k, v, start=start, kv_len=kv_len, sm_scale=sm_scale,
-                    m_scr=m_scr, l_scr=l_scr, acc_scr=acc_scr)
-
-    @pl.when(pi == n_pages - 1)
+    @pl.when(j == _n_blocks(limit, page_size, ppb) - 1)
     def _done():
-        _finalize(o_ref, m_scr, l_scr, acc_scr)
+        o_ref[0] = (acc_scr[...]
+                    / jnp.maximum(l_scr[...], 1e-30)[..., None]).astype(
+                        o_ref.dtype)
 
 
-def _kernel_bf16(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                 m_scr, l_scr, acc_scr, *, sm_scale, page_size, n_pages):
-    b = pl.program_id(0)
-    pi = pl.program_id(1)
+def _schedule(page_table, kv_len, *, page_size, ppb):
+    """The ragged grid's length and, for each of its ``B * ceil(W/ppb)``
+    possible steps, the slot, the block and (flat, operand-major) the pool
+    page each of the ``ppb`` page operands reads. A page past its slot's
+    context repeats the one its operand read last (the trash page before
+    any), so the pipeline, which skips a copy whose block does not change,
+    copies only pages that hold context. Steps run along the last axis and
+    gathers are flat, so nothing here pads a trailing axis of 1."""
+    B, W = page_table.shape
+    limit = _limit(kv_len, page_size, W)
+    n_live = pl.cdiv(limit, page_size)
+    n_blk = _n_blocks(limit, page_size, ppb)
+    ends = jnp.cumsum(n_blk)
+    n = B * pl.cdiv(W, ppb)
+    t = jnp.arange(n, dtype=jnp.int32)
+    slot = jnp.minimum((ends[:, None] <= t).sum(0), B - 1)
+    blk = t - (ends - n_blk)[slot]
+    p = blk * ppb + jnp.arange(ppb, dtype=jnp.int32)[:, None]   # (ppb, n)
+    live = (p < n_live[slot]) & (t < ends[-1])
+    page = page_table.reshape(-1)[slot * W + jnp.minimum(p, W - 1)]
+    last = jax.lax.cummax(jnp.where(live, t, -1), axis=1)
+    rows = jnp.arange(ppb, dtype=jnp.int32)[:, None] * n
+    page = jnp.where(last >= 0,
+                     page.reshape(-1)[rows + jnp.maximum(last, 0)], 0)
+    return ends[-1], slot, blk, jnp.maximum(page, 0).reshape(-1)
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[b]
-    start = pi * page_size
-
-    @pl.when(start < kv_len)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)            # (ps, Hkv, hd)
-        v = v_ref[0].astype(jnp.float32)
-        _accumulate(q, k, v, start=start, kv_len=kv_len, sm_scale=sm_scale,
-                    m_scr=m_scr, l_scr=l_scr, acc_scr=acc_scr)
-
-    @pl.when(pi == n_pages - 1)
-    def _done():
-        _finalize(o_ref, m_scr, l_scr, acc_scr)
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of one block in VMEM: its last two dims fill whole
+    (sublane, 128-lane) tiles, 8 sublanes of 32 bits."""
+    size = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // size
+    return (math.prod(lead) * -(-rows // sub) * sub
+            * -(-lanes // 128) * 128 * size)
 
 
-def grid_pages(batch: int, table_width: int) -> int:
-    """Page-table entries one launch of ``paged_decode_attention`` covers:
-    its grid is ``(batch, table_width)``, one page a step, whether the
-    page holds live context or not."""
-    return batch * table_width
+def pages_per_block(page_size: int, table_width: int, page_blocks) -> int:
+    """Pages a step attends over: ``BLOCK_TOKENS`` keys, fewer where the
+    two buffers of ``page_blocks`` (the ``(shape, dtype)`` of each
+    operand's one-page block) would pass ``BLOCK_VMEM_BYTES``, and never
+    more than the table holds."""
+    page = sum(_vmem_bytes(s, d) for s, d in page_blocks)
+    return max(1, min(BLOCK_TOKENS // page_size, table_width,
+                      BLOCK_VMEM_BYTES // (2 * page)))
+
+
+def grid_pages(kv_len, page_size: int) -> int:
+    """Pages one launch of ``paged_decode_attention`` copies from the pool
+    for the valid lengths ``kv_len``: each slot's pages that hold context
+    (an idle slot, of length 1, its trash page). Besides, the pipeline's
+    first step fills every page operand, so a first slot of fewer than a
+    block's pages adds as many copies of the trash page as it leaves
+    empty."""
+    return sum(-(-int(n) // page_size) for n in kv_len)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
@@ -149,57 +193,41 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
     B, Hkv, gq, hd = q.shape
     W = page_table.shape[1]
     sm_scale = sm_scale or 1.0 / math.sqrt(hd)
-    quantized = isinstance(k_pages, (tuple, list))
+    if isinstance(k_pages, (tuple, list)):
+        operands, load = (*k_pages, *v_pages), _load_int4
+    else:
+        operands, load = (k_pages, v_pages), _load_bf16
+    ppb = pages_per_block(page_size, W,
+                          [((1,) + a.shape[1:], a.dtype) for a in operands])
+    n_steps, slot, blk, page = _schedule(
+        page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
+        page_size=page_size, ppb=ppb)
+    kernel = functools.partial(_kernel, load=load, n_ops=len(operands),
+                               sm_scale=sm_scale, page_size=page_size,
+                               ppb=ppb, W=W)
 
-    def page_ix(b, pi, pt, ln):
-        # unallocated table entries are 0 (trash page); clamp defensively
-        return (jnp.maximum(pt[b, pi], 0), 0, 0)
+    n = slot.shape[0]
 
-    q_spec = pl.BlockSpec((1, Hkv, gq, hd), lambda b, pi, *_: (b, 0, 0, 0))
-    out_spec = pl.BlockSpec((1, Hkv, gq, hd), lambda b, pi, *_: (b, 0, 0, 0))
-    scratch = [pltpu.VMEM((Hkv, gq), jnp.float32),
-               pltpu.VMEM((Hkv, gq), jnp.float32),
-               pltpu.VMEM((Hkv, gq, hd), jnp.float32)]
+    def page_spec(a, i):
+        return pl.BlockSpec((1,) + a.shape[1:],
+                            lambda t, s, j, pg, ln: (pg[i * n + t],)
+                            + (0,) * (a.ndim - 1))
 
-    if quantized:
-        kp, ks, kz = k_pages
-        vp, vs, vz = v_pages
-        R, G2 = kp.shape[1], kp.shape[2]
-        kernel = functools.partial(_kernel_int4, sm_scale=sm_scale,
-                                   page_size=page_size, n_pages=W,
-                                   Hkv=Hkv, hd=hd)
-        pk_spec = pl.BlockSpec((1, R, G2), page_ix)
-        sc_spec = pl.BlockSpec((1, R, 1), page_ix)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, W),
-            in_specs=[q_spec, pk_spec, sc_spec, sc_spec,
-                      pk_spec, sc_spec, sc_spec],
-            out_specs=out_spec,
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(
-            kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, gq, hd), q.dtype),
-            interpret=interpret,
-        )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-          q, kp, ks, kz, vp, vs, vz)
-
-    kernel = functools.partial(_kernel_bf16, sm_scale=sm_scale,
-                               page_size=page_size, n_pages=W)
-    kd_spec = pl.BlockSpec((1, page_size, Hkv, hd),
-                           lambda b, pi, pt, ln: (jnp.maximum(pt[b, pi], 0),
-                                                  0, 0, 0))
+    q_spec = pl.BlockSpec((1, Hkv, gq, hd),
+                          lambda t, s, *_: (s[t], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, W),
-        in_specs=[q_spec, kd_spec, kd_spec],
-        out_specs=out_spec,
-        scratch_shapes=scratch,
+        num_scalar_prefetch=4,
+        grid=(n_steps,),
+        in_specs=[q_spec] + [page_spec(a, i) for a in operands
+                             for i in range(ppb)],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((Hkv, gq), jnp.float32),
+                        pltpu.VMEM((Hkv, gq), jnp.float32),
+                        pltpu.VMEM((Hkv, gq, hd), jnp.float32)],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, gq, hd), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(slot, blk, page, kv_len.astype(jnp.int32), q,
+      *[a for a in operands for _ in range(ppb)])

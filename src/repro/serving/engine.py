@@ -883,11 +883,14 @@ class DecodeEngine:
         if self.paged:
             # pages holding the live slots' context at the chunk's start
             # (the last emitted token's K/V is written by the chunk), and
-            # the page-table entries each launch of the kernel walks
+            # the pages each launch of the kernel copies: the first step
+            # attends over that token too, and an idle slot (length 0)
+            # over one key on the trash page
             ctx = [len(r.tokens) + len(r.out_tokens) - 1 for r in live]
+            kv_len = [c + 1 for c in ctx] + [1] * (self.max_slots - len(live))
             pages = {"live_pages": sum(-(-c // self.page_size) for c in ctx),
                      "grid_pages": paged_attention.grid_pages(
-                         self.max_slots, self.table_w)}
+                         kv_len, self.page_size)}
             self.decode_live_pages += pages["live_pages"] * n
             self.decode_grid_pages += pages["grid_pages"] * n
         self.decode_chunks += 1

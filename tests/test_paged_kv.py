@@ -29,6 +29,17 @@ KEY = jax.random.PRNGKey(0)
 # -- kernel parity -----------------------------------------------------------
 
 
+class Lens(list):
+    """Valid lengths of a kernel case, with the slots left ``idle`` on the
+    trash page (page-table rows of zeros, as the engine leaves a free
+    slot) and the leading pages slot 1 ``share``s with slot 0 (a prefix
+    chain both read)."""
+
+    def __init__(self, lens, idle=(), share=0):
+        super().__init__(lens)
+        self.idle, self.share = idle, share
+
+
 def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
                    resident="int4"):
     """Random dense K/V scattered into a shuffled page pool; returns
@@ -37,6 +48,8 @@ def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
     rng = np.random.default_rng(seed)
     B_ = len(kv_len)
     assert B_ == B
+    idle = getattr(kv_len, "idle", ())
+    share = getattr(kv_len, "share", 0)
     W = max(-(-int(l) // page_size) for l in kv_len)
     g = next(gg for gg in (128, 64, 32, 16, 8, 4, 2)
              if (Hkv * hd) % gg == 0)
@@ -46,12 +59,18 @@ def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
     perm = rng.permutation(np.arange(1, P))
     n = 0
     for b in range(B):
+        if b in idle:
+            continue
         k = -(-int(kv_len[b]) // page_size)
-        pt[b, :k] = perm[n:n + k]
-        n += k
+        own = share if b == 1 else 0
+        pt[b, :own] = pt[0, :own]
+        pt[b, own:k] = perm[n:n + k - own]
+        n += k - own
     S = W * page_size
     K = rng.standard_normal((B, S, Hkv, hd)).astype(np.float32)
     V = rng.standard_normal((B, S, Hkv, hd)).astype(np.float32)
+    K[1, :share * page_size] = K[0, :share * page_size]
+    V[1, :share * page_size] = V[0, :share * page_size]
 
     def to_pages(X):
         if resident == "bf16":
@@ -96,6 +115,13 @@ KERNEL_CASES = [
     (3, 2, 4, 16, 8, [5, 16, 23]),
     (2, 1, 8, 32, 16, [17, 48]),          # MQA, boundary-exact second seq
     (4, 4, 1, 16, 8, [1, 9, 24, 31]),     # MHA decode (gq=1)
+    # Mistral-7B's heads (g 128, ppr 8), 8 pages (128 keys) a step: an
+    # idle slot on the trash page, a block boundary and one key past it
+    (3, 8, 4, 128, 16, Lens([1, 128, 129], idle=(0,))),
+    # 16 pages a step: two whole blocks, the first slot filling its table
+    (3, 2, 4, 16, 8, Lens([256, 128, 129])),
+    # slot 1 reads slot 0's first 5 pages (a shared prefix chain)
+    (3, 2, 4, 16, 8, Lens([80, 150, 7], share=5)),
 ]
 
 
@@ -120,21 +146,69 @@ def test_paged_attention_matches_dense_ref(backend, B, Hkv, gq, hd, ps,
                                atol=tol, rtol=tol)
 
 
-def test_paged_attention_ignores_trash_and_masked_tail():
-    """Positions beyond kv_len (the tail of the last page) and table
-    entries pointing at the trash page must not leak into the output."""
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_paged_attention_ignores_trash_and_masked_tail(backend):
+    """Positions beyond kv_len (the tail of the last page), table entries
+    pointing at the trash page, and table entries past a slot's length
+    that name pages another slot owns must not leak into the output."""
     lens = [5, 16, 23]
     kv_len = jnp.asarray(lens, jnp.int32)
     q, kpg, vpg, pt, dk, dv = _paged_fixture(3, 2, 4, 16, 8, lens, seed=4)
-    out1 = ops.paged_decode_attention(q, kpg, vpg, pt, kv_len,
-                                      page_size=8, backend="ref")
-    # poison the trash page + every masked tail cell, rerun
-    kp, ks, kz = kpg
-    kp2 = kp.at[0].set(255)
-    ks2 = ks.at[0].set(1e6)
-    out2 = ops.paged_decode_attention(q, (kp2, ks2, kz), vpg, pt, kv_len,
-                                      page_size=8, backend="ref")
-    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+    # slots 0 and 1 keep stale entries past their lengths: slot 2's pages
+    pt = pt.at[0, 1:].set(pt[2, 1:]).at[1, 2].set(pt[2, 0])
+    run = lambda k, v: np.asarray(ops.paged_decode_attention(
+        q, k, v, pt, kv_len, page_size=8, backend=backend))
+    out1 = run(kpg, vpg)
+    # poison the trash page, every masked tail row and slot 2's pages
+    ppr = kpg[0].shape[1] // 8
+    tails = [(int(pt[b, l // 8]), (l % 8) * ppr)
+             for b, l in enumerate(lens) if l % 8]
+
+    def poison(pages):
+        out = []
+        for a, bad in zip(pages, (255, 1e6, -1e6)):
+            a = a.at[0].set(bad).at[pt[2]].set(bad)
+            for page, row in tails:
+                a = a.at[page, row:].set(bad)
+            out.append(a)
+        return tuple(out)
+    out2 = run(poison(kpg), poison(vpg))
+    np.testing.assert_array_equal(out1[:2], out2[:2])
+    assert np.isfinite(out2[:2]).all()
+
+
+def test_paged_schedule_copies_the_counted_pages():
+    """The ragged grid's schedule: a step per block of context (one at
+    least a slot), and a page past a slot's context repeating what its
+    operand read last, so the pipeline copies ``grid_pages`` pages, plus
+    the trash page for each operand the first slot's block leaves
+    empty. Distinct pages, so no copy is skipped by chance."""
+    from repro.kernels import paged_attention as pa
+    ps, ppb, W = 8, 4, 12
+    lens = [3, 1, 33, 96, 0, 32]
+    n_live = [-(-n // ps) for n in lens]
+    pt = np.zeros((len(lens), W), np.int32)
+    nxt = 1
+    for b, n in enumerate(n_live):
+        pt[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    n_steps, slot, blk, page = pa._schedule(
+        jnp.asarray(pt), jnp.asarray(lens, jnp.int32), page_size=ps,
+        ppb=ppb)
+    n_steps = int(n_steps)
+    assert n_steps == 1 + 1 + 2 + 3 + 1 + 1
+    page = np.asarray(page).reshape(ppb, -1).T[:n_steps]
+    copies = 1 + (page[1:] != page[:-1]).sum(0)
+    # operand 0 reads a page in every step but the empty slot's; the
+    # others copy the trash page first, then what slots 2, 3 and 5 fill
+    assert copies.tolist() == [8, 6, 6, 6]
+    assert copies.sum() == pa.grid_pages(lens, ps) + ppb - n_live[0]
+    # every page of context is read, in its slot's steps, at its place
+    slot, blk = np.asarray(slot)[:n_steps], np.asarray(blk)[:n_steps]
+    for b, n in enumerate(n_live):
+        for p in range(n):
+            t, = np.nonzero((slot == b) & (blk == p // ppb))
+            assert page[t[0], p % ppb] == pt[b, p]
 
 
 # -- PagePool invariants ------------------------------------------------------

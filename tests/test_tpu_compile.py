@@ -1,5 +1,6 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a TPU
-v5e that is described, not attached, at gemma-2b's published widths.
+v5e that is described, not attached, at gemma-2b's published widths (and
+the paged decode kernel at the benchmark's Mistral-7B engine shapes too).
 
 Interpret mode accepts casts and reshapes that the chip's compiler
 (Mosaic) refuses, so the kernel tests elsewhere cannot catch those. Each
@@ -7,6 +8,8 @@ compile here is what the chip would compile, and fails the same way. The
 topology is described inside a fixture, never at import: only one process
 may load the TPU compiler library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,20 +54,6 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _int4_pages(spec):
-    packed = spec((PAGES, ROWS, G // 2), jnp.uint8)
-    scale = spec((PAGES, ROWS, 1), jnp.float32)
-    return packed, scale, scale
-
-
-def _decode_inputs(spec):
-    Hkv = CFG.num_kv_heads
-    q = spec((SLOTS, Hkv, CFG.num_heads // Hkv, CFG.head_dim), jnp.bfloat16)
-    table = spec((SLOTS, paged.table_width(MAX_SEQ, PAGE_SIZE)), jnp.int32)
-    lens = spec((SLOTS,), jnp.int32)
-    return q, table, lens
-
-
 def test_kv_quant_compiles(spec):
     x = spec((WIRE_ROWS, G), jnp.bfloat16)
     text = _compiled_text(lambda x: ops.kv_quant(x, backend="pallas"), x)
@@ -80,19 +69,38 @@ def test_kv_dequant_compiles(spec):
     assert "tpu_custom_call" in text
 
 
+# (Hkv, gq, hd, slots, table width, pages, ppr) of a decode engine's pool:
+# gemma-2b as above; the benchmark's mistral-7b-l16 engine (32 slots of
+# 5120 tokens, 6145 16-token pages, g 128 over 8 KV heads of 128)
+PAGED_SHAPES = {
+    "gemma-2b": (CFG.num_kv_heads, CFG.num_heads // CFG.num_kv_heads,
+                 CFG.head_dim, SLOTS, paged.table_width(MAX_SEQ, PAGE_SIZE),
+                 PAGES, PPR),
+    "mistral-7b-l16": (8, 4, 128, 32, 320, 6145, 8),
+}
+
+
+@pytest.mark.parametrize("shapes", PAGED_SHAPES)
 @pytest.mark.parametrize("resident", ["int4", "bf16"])
-def test_paged_decode_attention_compiles(spec, resident):
-    q, table, lens = _decode_inputs(spec)
+def test_paged_decode_attention_compiles(spec, resident, shapes):
+    Hkv, gq, hd, slots, W, P, ppr = PAGED_SHAPES[shapes]
+    g = Hkv * hd // ppr
+    q = spec((slots, Hkv, gq, hd), jnp.bfloat16)
+    table = spec((slots, W), jnp.int32)
+    lens = spec((slots,), jnp.int32)
     if resident == "int4":
-        k = v = _int4_pages(spec)
+        rows = PAGE_SIZE * ppr
+        scale = spec((P, rows, 1), jnp.float32)
+        k = v = (spec((P, rows, g // 2), jnp.uint8), scale, scale)
     else:
-        k = v = spec((PAGES, PAGE_SIZE, CFG.num_kv_heads, CFG.head_dim),
-                     jnp.bfloat16)
+        k = v = spec((P, PAGE_SIZE, Hkv, hd), jnp.bfloat16)
     text = _compiled_text(
         lambda q, k, v, t, n: ops.paged_decode_attention(
             q, k, v, t, n, page_size=PAGE_SIZE, backend="pallas"),
         q, k, v, table, lens)
-    assert "tpu_custom_call" in text
+    calls = re.findall(r"%([\w.-]+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert [c.split(".")[0] for c in calls] == ["paged_decode_attention"]
 
 
 def test_paged_decode_step_compiles_and_fits(spec):

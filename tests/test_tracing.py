@@ -116,25 +116,28 @@ def test_prefill_counts_prompt_and_padded_tokens(small_model, recorded):
 
 
 def test_decode_chunk_counts_live_and_walked_pages(small_model, recorded):
-    """8-token pages, 4 slots of a 64-token table (8 entries): each kernel
-    launch walks 32 entries; prompts of 8, 17 and 24 tokens hold 1 + 3 + 3
-    pages at the first chunk's start and 2 + 3 + 4 after its 4 steps."""
+    """8-token pages, 4 slots: prompts of 8, 17 and 24 tokens hold 1 + 3 +
+    3 pages at the first chunk's start and 2 + 3 + 4 after its 4 steps.
+    Each kernel launch copies the pages its keys fill, the token each step
+    appends included, and the idle slot's trash page: 2 + 3 + 4 + 1 at
+    the first chunk, where the 8- and 24-token contexts end on a page
+    boundary, and 2 + 3 + 4 + 1 at the second."""
     cfg, params = small_model
     pre = PrefillEngine(cfg, params, max_seq=64)
     eng = DecodeEngine(cfg, params, max_slots=4, max_seq=64, chunk_size=4,
                        paged=True, page_size=8)
-    assert paged_attention.grid_pages(4, eng.table_w) == 32
+    assert paged_attention.grid_pages([9, 18, 25, 1], 8) == 10
     assert not admit_many(eng, pre.run(_reqs(cfg, [8, 17, 24], 12),
                                        backend="ref"), backend="ref")
     eng.step()
     eng.step()
     first, second = _named(recorded, "decode_chunk")
     assert first.attrs == {"rids": [0, 1, 2], "live_slots": 3, "steps": 4,
-                           "live_pages": 7, "grid_pages": 32}
-    assert second.attrs["live_pages"] == 9
+                           "live_pages": 7, "grid_pages": 10}
+    assert (second.attrs["live_pages"], second.attrs["grid_pages"]) == (9, 10)
     st = eng.page_stats()
     assert (st["decode_chunks"], st["decode_live_pages"],
-            st["decode_grid_pages"]) == (2, (7 + 9) * 4, 2 * 32 * 4)
+            st["decode_grid_pages"]) == (2, (7 + 9) * 4, (10 + 10) * 4)
     # the host read-back sits inside its chunk
     assert [r.parent for r in _named(recorded, "readback")] == \
         [first.seq, second.seq]
