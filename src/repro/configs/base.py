@@ -64,6 +64,7 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0
     embed_scale: bool = False        # scale embeddings by sqrt(d_model) (gemma)
     tie_embeddings: bool = False
+    logit_scale: float = 1.0         # multiplies the head's logits (command-r)
 
     # MoE
     moe_num_experts: int = 0

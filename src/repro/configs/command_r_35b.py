@@ -1,7 +1,10 @@
-"""command-r-35b [dense] — 40L d_model=8192 64H (GQA kv=8) d_ff=22528 vocab=256000.
+"""command-r-35b [dense] — 40L d_model=8192 64H (MHA: kv=64) d_ff=22528 vocab=256000.
 
-Cohere Command-R: parallel attention+FFN residual blocks, LayerNorm, no bias,
-tied embeddings, logit softcap absent. [hf:CohereForAI/c4ai-command-r-v01; unverified]
+Cohere Command-R v01 (35.0B): parallel attention+FFN residual blocks under
+one LayerNorm without bias, tied embeddings, logits times ``logit_scale``
+0.0625, no softcap. [hf:CohereForAI/c4ai-command-r-v01]
+The 08-2024 release (32B) keeps the block but has 8 KV heads and d_ff
+24576; the benchmark serves it from ``bench/configs/command-r-32b-l4.json``.
 """
 from repro.configs.base import ModelConfig
 
@@ -11,7 +14,7 @@ CONFIG = ModelConfig(
     num_layers=40,
     d_model=8192,
     num_heads=64,
-    num_kv_heads=8,
+    num_kv_heads=64,
     head_dim=128,
     d_ff=22528,
     vocab_size=256000,
@@ -20,6 +23,7 @@ CONFIG = ModelConfig(
     parallel_block=True,
     rope_theta=8000000.0,
     tie_embeddings=True,
+    logit_scale=0.0625,
 )
 
 

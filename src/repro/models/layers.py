@@ -298,6 +298,18 @@ def attn_qkv(cfg, p, x, positions):
     return q, k, v
 
 
+def attn_out(cfg, p, q, k, v, *, causal=True, window=None, kv_len=None,
+             q_offset=None):
+    """Attention of ``q (B, S, H, hd)`` over ``k``/``v`` and the output
+    projection."""
+    win = cfg.sliding_window if window is None else window
+    o = attention(q, k, v, causal=causal, window=win, kv_len=kv_len,
+                  softcap=cfg.attn_logit_softcap, q_offset=q_offset,
+                  chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv)
+    B, S = q.shape[:2]
+    return dense(p["wo"], o.reshape(B, S, cfg.q_dim))
+
+
 def attn_apply(cfg, p, x, *, positions, causal=True, window=None,
                kv_cache=None, kv_len=None, q_offset=None):
     """Self-attention. If kv_cache=(K,V) given, attends over cache (decode).
@@ -306,18 +318,9 @@ def attn_apply(cfg, p, x, *, positions, causal=True, window=None,
     (for cache insertion by the caller).
     """
     q, k, v = attn_qkv(cfg, p, x, positions)
-    win = cfg.sliding_window if window is None else window
-    if kv_cache is not None:
-        K, V = kv_cache
-        o = attention(q, K, V, causal=causal, window=win, kv_len=kv_len,
-                      softcap=cfg.attn_logit_softcap, q_offset=q_offset,
-                      chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv)
-    else:
-        o = attention(q, k, v, causal=causal, window=win, kv_len=kv_len,
-                      softcap=cfg.attn_logit_softcap, q_offset=q_offset,
-                      chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv)
-    B, S = x.shape[:2]
-    out = dense(p["wo"], o.reshape(B, S, cfg.q_dim))
+    K, V = kv_cache if kv_cache is not None else (k, v)
+    out = attn_out(cfg, p, q, K, V, causal=causal, window=window,
+                   kv_len=kv_len, q_offset=q_offset)
     return out, (k, v)
 
 

@@ -44,8 +44,8 @@ from repro.kernels import ops
 from repro.kernels.ref import kv_quant_ref
 from repro.models import layers
 from repro.models.layers import dense, norm_apply
-from repro.models.transformer import (_apply_ffn, _embed_inputs, slot_kinds,
-                                      unembed_matrix)
+from repro.models.transformer import (_apply_ffn, _embed_inputs, head_logits,
+                                      slot_kinds)
 # the ONE group-selection rule (kernels/kv_layout.py — no cycle): pages
 # must pick groups exactly like the wire's padded-extract path or
 # zero-copy insertion silently degrades to re-encoding (lint rule R005)
@@ -220,8 +220,7 @@ def decode_step_paged(cfg, params, cache, tokens, *, rt=None,
     x, block_cache = lax.fori_loop(0, n_super, body, (x, block_cache))
     with jax.named_scope("decode/head"):
         x = norm_apply(cfg, params["final_norm"], x)
-        w = unembed_matrix(cfg, params)
-        logits = (x @ w).astype(jnp.float32)
+        logits = head_logits(cfg, params, x)
     new_cache = dict(block_cache)
     new_cache["page_table"] = pt
     new_cache["lengths"] = cache["lengths"] + 1

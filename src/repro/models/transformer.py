@@ -127,18 +127,26 @@ def _block_apply(cfg, p, kind, x, *, positions, mode, cache_slot, q_offset,
     """Apply one block. Returns (x, new_cache_slot, aux_loss).
 
     mode: "full" (train/prefill over a whole sequence; new KV returned for
-    cache construction) or "step" (decode: read+update cache).
+    cache construction) or "step" (decode: read+update cache). In "full"
+    mode device work sits under named scopes (operation metadata only):
+    ``qkv`` (the norm and the projections), ``attn`` (attention and its
+    output projection) and ``ffn`` (the MLP, the parallel block's too).
     """
     mix = kind.split("+")[0]
-    h = norm_apply(cfg, p["norm1"], x)
+    with jax.named_scope("qkv"):
+        h = norm_apply(cfg, p["norm1"], x)
     aux = 0.0
     new_slot = cache_slot
 
     if mix == "attn":
         if mode == "full":
-            out, (k_new, v_new) = layers.attn_apply(
-                cfg, p["attn"], h, positions=positions, causal=True,
-                window=window, kv_len=kv_len, q_offset=q_offset)
+            with jax.named_scope("qkv"):
+                q, k_new, v_new = layers.attn_qkv(cfg, p["attn"], h,
+                                                  positions)
+            with jax.named_scope("attn"):
+                out = layers.attn_out(cfg, p["attn"], q, k_new, v_new,
+                                      window=window, kv_len=kv_len,
+                                      q_offset=q_offset)
             new_slot = (k_new, v_new)
         else:  # decode step against cache
             K, V = cache_slot["k"], cache_slot["v"]
@@ -199,13 +207,15 @@ def _block_apply(cfg, p, kind, x, *, positions, mode, cache_slot, q_offset,
     out = constrain(out, "batch", "seq", None)
     if cfg.parallel_block and "mlp" in p:
         # parallel residual: x + attn(n(x)) + mlp(n(x)) (single shared norm)
-        out = out + layers.mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("ffn"):
+            out = out + layers.mlp_apply(cfg, p["mlp"], h)
         x = x + out
     else:
         x = x + out
         if kind.split("+")[1] != "none" and not (
                 cfg.parallel_block and "mlp" in p):
-            delta, aux = _apply_ffn(cfg, p, kind, x, rt)
+            with jax.named_scope("ffn"):
+                delta, aux = _apply_ffn(cfg, p, kind, x, rt)
             x = x + delta
     x = constrain(x, "batch", "seq", None)
     return x, new_slot, aux
@@ -257,6 +267,16 @@ def unembed_matrix(cfg, params):
     return params["unembed"]
 
 
+def head_logits(cfg, params, h):
+    """f32 logits of final-normed hidden states ``h``: the output head,
+    times ``cfg.logit_scale`` where that is not 1 (a scale of 1 adds no
+    operation to the program)."""
+    logits = (h @ unembed_matrix(cfg, params)).astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    return logits
+
+
 def _embed_inputs(cfg, params, tokens, patch_embeds=None):
     x = params["embed"][tokens]
     if cfg.embed_scale:
@@ -293,7 +313,8 @@ def _stack_scan(cfg, params, x, body, *, remat=None):
 
 def forward(cfg, params, tokens, *, patch_embeds=None, rt=None,
             collect_cache=False, window=None):
-    """Full-sequence forward. Returns (hidden, stacked_new_cache, aux_loss)."""
+    """Full-sequence forward. Returns (hidden, stacked_new_cache, aux_loss).
+    The final norm sits under the named scope ``head``."""
     kinds = slot_kinds(cfg)
     x = _embed_inputs(cfg, params, tokens, patch_embeds)
     x = constrain(x, "batch", "seq", None)
@@ -325,7 +346,8 @@ def forward(cfg, params, tokens, *, patch_embeds=None, rt=None,
     else:
         caches = None
         aux_total = jnp.sum(ys) if ys is not None else jnp.float32(0.0)
-    x = norm_apply(cfg, params["final_norm"], x)
+    with jax.named_scope("head"):
+        x = norm_apply(cfg, params["final_norm"], x)
     return x, caches, aux_total
 
 
@@ -336,6 +358,8 @@ def loss_fn(cfg, params, batch, *, rt=None):
                         patch_embeds=batch.get("patch_embeds"), rt=rt)
     S_t = batch["targets"].shape[1]
     x = x[:, -S_t:]  # VLM: loss only over text positions
+    if cfg.logit_scale != 1.0:
+        x = x * jnp.asarray(cfg.logit_scale, x.dtype)
     w = unembed_matrix(cfg, params)
     nll = layers.chunked_xent(x, w, batch["targets"], chunk=cfg.vocab_chunk,
                               mask=batch.get("mask"))
@@ -369,7 +393,11 @@ def prefill(cfg, params, tokens, *, max_seq: int, patch_embeds=None, rt=None,
     ``last_pos``/``true_len`` support right-padded (bucketed) prompts:
     logits are gathered at each sequence's own last real position instead of
     ``S-1``, and the cache lengths record the real (unpadded) lengths.
-    Causal attention makes right padding invisible to the real positions."""
+    Causal attention makes right padding invisible to the real positions.
+
+    Device work sits under the named scopes ``qkv``, ``attn``, ``ffn``
+    (``_block_apply``) and ``head`` (the final norm and the head); the
+    prefill engine puts them under ``prefill``."""
     x, caches, _ = forward(cfg, params, tokens, patch_embeds=patch_embeds,
                            rt=rt, collect_cache=True, window=window)
     B, S = x.shape[:2]
@@ -405,12 +433,12 @@ def prefill(cfg, params, tokens, *, max_seq: int, patch_embeds=None, rt=None,
             cache[f"slot{s}"] = got
     cache["lengths"] = (jnp.full((B,), S, jnp.int32) if true_len is None
                         else true_len.astype(jnp.int32))
-    w = unembed_matrix(cfg, params)
-    if last_pos is None:
-        h_last = x[:, -1:]
-    else:
-        h_last = x[jnp.arange(B), last_pos][:, None]
-    logits = (h_last @ w).astype(jnp.float32)
+    with jax.named_scope("head"):
+        if last_pos is None:
+            h_last = x[:, -1:]
+        else:
+            h_last = x[jnp.arange(B), last_pos][:, None]
+        logits = head_logits(cfg, params, h_last)
     return logits, cache
 
 
@@ -429,7 +457,8 @@ def prefill_suffix(cfg, params, tokens, prefix_kv, prefix_len, *,
     holds ONLY the suffix KV at rows ``0..S-1`` (lengths = suffix true
     lengths), so the bucketed wire extraction is unchanged — wire token
     ``t`` is absolute position ``prefix_len + t``, spliced onto the
-    shared chain at a page boundary by the decode side.
+    shared chain at a page boundary by the decode side. Device work sits
+    under the named scopes of :func:`prefill`.
 
     Pure-attention stacks only (the same families the paged pool
     serves); recurrent state cannot be sliced at a position boundary.
@@ -451,20 +480,23 @@ def prefill_suffix(cfg, params, tokens, prefix_kv, prefix_len, *,
         for s, kind in enumerate(kinds):
             p = blk[f"slot{s}"]
             pk, pv = pkv[f"slot{s}"]
-            h = norm_apply(cfg, p["norm1"], x)
-            q, k, v = layers.attn_qkv(cfg, p["attn"], h, positions)
-            K = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-            V = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-            o = _suffix_attention(cfg, q, K, V, prefix_len)
-            out = dense(p["attn"]["wo"], o.reshape(B, S, cfg.q_dim))
+            with jax.named_scope("qkv"):
+                h = norm_apply(cfg, p["norm1"], x)
+                q, k, v = layers.attn_qkv(cfg, p["attn"], h, positions)
+            with jax.named_scope("attn"):
+                K = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+                V = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+                o = _suffix_attention(cfg, q, K, V, prefix_len)
+                out = dense(p["attn"]["wo"], o.reshape(B, S, cfg.q_dim))
             out = constrain(out, "batch", "seq", None)
-            if cfg.parallel_block and "mlp" in p:
-                x = x + out + layers.mlp_apply(cfg, p["mlp"], h)
-            else:
-                x = x + out
-                if kind.split("+")[1] != "none":
-                    delta, _ = _apply_ffn(cfg, p, kind, x, rt)
-                    x = x + delta
+            with jax.named_scope("ffn"):
+                if cfg.parallel_block and "mlp" in p:
+                    x = x + out + layers.mlp_apply(cfg, p["mlp"], h)
+                else:
+                    x = x + out
+                    if kind.split("+")[1] != "none":
+                        delta, _ = _apply_ffn(cfg, p, kind, x, rt)
+                        x = x + delta
             x = constrain(x, "batch", "seq", None)
             slots_out[f"slot{s}"] = (k, v)
         return x, slots_out
@@ -480,7 +512,8 @@ def prefill_suffix(cfg, params, tokens, prefix_kv, prefix_len, *,
             x, y = body(x, (blk_i, pkv_i))
             ys.append(y)
         caches = jax.tree.map(lambda *a: jnp.stack(a), *ys)
-    x = norm_apply(cfg, params["final_norm"], x)
+    with jax.named_scope("head"):
+        x = norm_apply(cfg, params["final_norm"], x)
 
     cache = init_cache(cfg, B, max_seq,
                        jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
@@ -501,12 +534,12 @@ def prefill_suffix(cfg, params, tokens, prefix_kv, prefix_len, *,
             v_upd.astype(cache[f"slot{s}"]["v"].dtype), (0, 0, 0, 0, 0))
     cache["lengths"] = (jnp.full((B,), S, jnp.int32) if true_len is None
                         else true_len.astype(jnp.int32))
-    w = unembed_matrix(cfg, params)
-    if last_pos is None:
-        h_last = x[:, -1:]
-    else:
-        h_last = x[jnp.arange(B), last_pos][:, None]
-    logits = (h_last @ w).astype(jnp.float32)
+    with jax.named_scope("head"):
+        if last_pos is None:
+            h_last = x[:, -1:]
+        else:
+            h_last = x[jnp.arange(B), last_pos][:, None]
+        logits = head_logits(cfg, params, h_last)
     return logits, cache
 
 
@@ -658,8 +691,7 @@ def decode_step_inplace(cfg, params, cache, tokens, *, rt=None, window=None):
 
     x, block_cache = lax.fori_loop(0, n_super, body, (x, block_cache))
     x = norm_apply(cfg, params["final_norm"], x)
-    w = unembed_matrix(cfg, params)
-    logits = (x @ w).astype(jnp.float32)
+    logits = head_logits(cfg, params, x)
     new_cache = dict(block_cache)
     new_cache["lengths"] = cache["lengths"] + 1
     return logits, new_cache
@@ -703,7 +735,6 @@ def decode_step(cfg, params, cache, tokens, *, rt=None, window=None):
             ys.append(y)
         new_cache = jax.tree.map(lambda *a: jnp.stack(a), *ys)
     x = norm_apply(cfg, params["final_norm"], x)
-    w = unembed_matrix(cfg, params)
-    logits = (x @ w).astype(jnp.float32)
+    logits = head_logits(cfg, params, x)
     new_cache["lengths"] = cache["lengths"] + 1
     return logits, new_cache

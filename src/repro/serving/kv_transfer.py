@@ -16,6 +16,7 @@ no host round-trip ever happens.
 """
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,7 +49,48 @@ class WireTensor:
     dtype: str = "bfloat16"
 
     def nbytes(self) -> int:
+        if isinstance(self.payload, PaddedRows):
+            return self.payload.nbytes()
         return int(sum(a.nbytes for a in self.payload.values()))
+
+
+class PaddedRows(Mapping):
+    """An int4 payload cut to a request's ``ln`` positions out of the rows
+    of its padded prefill bucket (``lp`` positions, position-aligned
+    groups), cut on first read. ``rows`` keeps the bucket's rows: the page
+    insert scatters them whole, dropping positions from ``ln`` on, so it
+    compiles one program per bucket where a cut payload would compile one
+    per prompt length."""
+
+    def __init__(self, rows: Dict[str, jnp.ndarray], L: int, lp: int,
+                 ln: int):
+        self.rows, self.L, self.lp, self.ln = rows, L, lp, ln
+        self._cut: Optional[Dict[str, jnp.ndarray]] = None
+
+    def cut(self, rows=None) -> Dict[str, jnp.ndarray]:
+        """``rows`` (the bucket's, or host copies of them) cut to ``ln``
+        positions."""
+        out = {}
+        for key, a in (self.rows if rows is None else rows).items():
+            out[key] = a.reshape(self.L, self.lp, -1, a.shape[1])[
+                :, :self.ln].reshape(-1, a.shape[1])
+        return out
+
+    def nbytes(self) -> int:
+        """Bytes of the cut payload (rows are proportional to positions)."""
+        return int(sum(a.nbytes for a in self.rows.values())
+                   * self.ln // self.lp)
+
+    def __getitem__(self, key):
+        if self._cut is None:
+            self._cut = self.cut()
+        return self._cut[key]
+
+    def __iter__(self):
+        return iter(self.rows)
+
+    def __len__(self):
+        return len(self.rows)
 
 
 @dataclass
@@ -68,9 +110,12 @@ class KVWire:
         when the transfer crosses a real network boundary; skip it for
         in-process handoff (the decode side consumes device arrays)."""
         tensors = [t for s in self.slots.values() for t in s.values()]
-        host = jax.device_get([t.payload for t in tensors])
+        host = jax.device_get([t.payload.rows
+                               if isinstance(t.payload, PaddedRows)
+                               else t.payload for t in tensors])
         for t, p in zip(tensors, host):
-            t.payload = p
+            t.payload = (t.payload.cut(p)
+                         if isinstance(t.payload, PaddedRows) else p)
         return self
 
 
@@ -178,7 +223,8 @@ def extract(cache, batch_index: int, length: int, *, compress: bool = True,
 
 def _trim_wire_tensor(wt: WireTensor, ln: int) -> WireTensor:
     """Cut a padded-length int4 WireTensor down to the request's true
-    length (lazy device slicing — no dequantization, no kernel launch).
+    length: the payload becomes a :class:`PaddedRows` over the bucket's
+    rows, cut on first read (no dequantization, no kernel launch).
 
     Requires the quantization groups to be position-aligned (group width
     divides Hkv*hd), which ``extract_batch`` checks before taking the
@@ -188,14 +234,8 @@ def _trim_wire_tensor(wt: WireTensor, ln: int) -> WireTensor:
     L, lp, Hkv, hd = wt.orig_shape
     if ln >= lp:
         return wt
-    packed = wt.payload["packed"]
-    g2 = packed.shape[1]                      # group//2 packed bytes
-    ppr = (Hkv * hd) // (2 * g2)              # quant rows per position
-    out = {}
-    for key, a in wt.payload.items():
-        out[key] = a.reshape(L, lp, ppr, a.shape[1])[:, :ln].reshape(
-            -1, a.shape[1])
-    return WireTensor("int4", out, (L, ln, Hkv, hd), wt.dtype)
+    return WireTensor("int4", PaddedRows(dict(wt.payload), L, lp, ln),
+                      (L, ln, Hkv, hd), wt.dtype)
 
 
 def extract_batch(cache, requests: Sequence[Tuple[int, int]], *,

@@ -26,6 +26,7 @@ stack installed.
 """
 from __future__ import annotations
 
+import functools
 from typing import TYPE_CHECKING, Dict, Hashable, List, Optional, \
     Sequence, Set, Tuple
 
@@ -248,32 +249,67 @@ def _wire_rows_aligned(wt: WireTensor, g: int, ppr: int) -> bool:
     rows: same group width, groups never straddling token positions."""
     if wt.kind != "int4":
         return False
+    from repro.serving.kv_transfer import PaddedRows
     L, ln, Hkv, hd = wt.orig_shape
-    packed = wt.payload["packed"]
+    if isinstance(wt.payload, PaddedRows):
+        L, ln = wt.payload.L, wt.payload.lp
+        packed = wt.payload.rows["packed"]
+    else:
+        packed = wt.payload["packed"]
     return (packed.shape[1] == g // 2
             and packed.shape[0] == L * ln * ppr)
 
 
 def _wire_to_rows(wt: WireTensor, cfg, backend: str):
     """Return (packed, scale, zero) rows in page row-order for one wire
-    tensor — zero-copy when the wire layout already matches, otherwise
-    re-encoded via one dequant+quant (device ops, no host sync)."""
+    tensor, the positions they cover (a padded wire's whole bucket) and
+    whether they came zero-copy — otherwise re-encoded via one
+    dequant+quant (device ops, no host sync)."""
     import jax.numpy as jnp
 
     from repro.kernels import ops
     from repro.models import paged
-    from repro.serving.kv_transfer import _dequantize
+    from repro.serving.kv_transfer import PaddedRows, _dequantize
     g = paged.page_group(cfg)
     ppr = paged.groups_per_token(cfg)
     if _wire_rows_aligned(wt, g, ppr):
-        return (jnp.asarray(wt.payload["packed"]),
-                jnp.asarray(wt.payload["scale"]),
-                jnp.asarray(wt.payload["zero"]), True)
+        if isinstance(wt.payload, PaddedRows):
+            rows, n_pos = wt.payload.rows, wt.payload.lp
+        else:
+            rows, n_pos = wt.payload, wt.orig_shape[1]
+        return (tuple(jnp.asarray(rows[k])
+                      for k in ("packed", "scale", "zero")), n_pos, True)
     dense = _dequantize(wt, backend)               # (L, ln, Hkv, hd)
     L, ln = dense.shape[:2]
     rows = dense.reshape(L * ln * ppr, g)
-    packed, scale, zero = ops.kv_quant(rows, backend=backend)
-    return packed, scale, zero, False
+    return ops.kv_quant(rows, backend=backend), ln, False
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_rows_fn():
+    """The jitted scatter of one wire tensor's (packed, scale, zero) rows,
+    covering ``n_pos`` positions, into a slot's pages. Positions from
+    ``ln`` on (a padded bucket's tail) index past the pool and are
+    dropped, so ``ln`` is a traced scalar: one program per bucket shape,
+    not per prompt length."""
+    import jax
+    import jax.numpy as jnp
+
+    def scatter(dsts, vals, pages, ln, page_size):
+        L, n_pages, R = dsts[0].shape[:3]
+        ppr = R // page_size
+        n_pos = vals[0].shape[0] // (L * ppr)
+        tpos = jnp.arange(n_pos)
+        pg = jnp.where(tpos < ln, pages[tpos // page_size],
+                       n_pages)[:, None]                       # (n_pos, 1)
+        rows = ((tpos % page_size)[:, None] * ppr
+                + jnp.arange(ppr)[None])                       # (n_pos, ppr)
+        return tuple(
+            d.at[:, pg, rows].set(
+                v.reshape(L, n_pos, ppr, v.shape[1]).astype(d.dtype),
+                mode="drop")
+            for d, v in zip(dsts, vals))
+    return jax.jit(scatter, static_argnames=("page_size",))
 
 
 def insert_wires(cache, cfg, items: Sequence[Tuple], *,
@@ -303,12 +339,9 @@ def _insert_wires(cache, cfg, items: Sequence[Tuple], backend: str):
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.models import paged
     from repro.serving.kv_transfer import _dequantize
     int4 = "kp" in cache["slot0"]
     ps = cache_page_size(cache, cfg)
-    ppr = paged.groups_per_token(cfg)
-    g = paged.page_group(cfg)
     W = cache["page_table"].shape[1]
     n_zero, n_reenc = 0, 0
     for item in items:
@@ -330,20 +363,18 @@ def _insert_wires(cache, cfg, items: Sequence[Tuple], backend: str):
                 if wt is None:
                     continue
                 if int4:
-                    packed, scale, zero, aligned = _wire_to_rows(
-                        wt, cfg, backend)
+                    vals, n_pos, aligned = _wire_to_rows(wt, cfg, backend)
                     n_zero += int(aligned)
                     n_reenc += int(not aligned)
-                    L = wt.orig_shape[0]
-                    rows = ((tpos % ps)[:, None] * ppr
-                            + np.arange(ppr)[None])                 # (ln,ppr)
-                    pg = dst_page[:, None]
-                    for suffix, val, width in (
-                            ("p", packed, g // 2), ("s", scale, 1),
-                            ("z", zero, 1)):
-                        dst = buf[base + suffix]
-                        cache[name][base + suffix] = dst.at[:, pg, rows].set(
-                            val.reshape(L, ln, ppr, width).astype(dst.dtype))
+                    n_pg = -(-n_pos // ps)
+                    pg = np.zeros((n_pg,), np.int32)
+                    pg[:min(n_pg, len(pages))] = pages[:n_pg]
+                    keys = [base + sfx for sfx in ("p", "s", "z")]
+                    out = _scatter_rows_fn()(
+                        tuple(buf[k] for k in keys), vals, jnp.asarray(pg),
+                        ln, page_size=ps)
+                    for k, a in zip(keys, out):
+                        cache[name][k] = a
                 else:
                     dense = _dequantize(wt, backend)     # (L, ln, Hkv, hd)
                     dst = buf[base]

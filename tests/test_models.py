@@ -67,12 +67,12 @@ def test_prefill_decode_matches_forward(arch):
     if cfg.family == "audio":
         from repro.models import whisper
         x = whisper.forward(cfg, params, tokens, batch["frame_embeds"])
-        w = params["unembed"]
+        full_logits = np.asarray((x @ params["unembed"]).astype(jnp.float32))
     else:
         x, _, _ = transformer.forward(
             cfg, params, tokens, patch_embeds=batch.get("patch_embeds"))
-        w = transformer.unembed_matrix(cfg, params)
-    full_logits = np.asarray((x @ w).astype(jnp.float32))
+        # the program's head, with its logit scale
+        full_logits = np.asarray(transformer.head_logits(cfg, params, x))
     if cfg.family == "vlm":
         full_logits = full_logits[:, cfg.num_patches:]
 

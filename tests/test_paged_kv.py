@@ -8,6 +8,8 @@ returns every page; finish returns every page; admission is page-budget
 gated), zero-dequant wire insertion, phase-flip pool ownership, and the
 cost model's page-budget capacity term.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -394,6 +396,50 @@ def test_paged_zero_dequant_inserts_from_bucketed_wire(small_model):
                            backend="ref"):
         assert admit_one(eng2, r, f, wire=w, backend="ref")
     assert eng2.reencoded_inserts > 0 and eng2.zero_copy_inserts == 0
+
+
+def test_padded_wire_inserts_its_cut_rows_with_one_program_a_bucket(
+        small_model):
+    """A bucketed wire keeps its bucket's rows (``PaddedRows``): the page
+    insert scatters them whole, drops the tail past the prompt, and fills
+    the pages exactly as the payload cut to the prompt does, with one
+    compile for every prompt length of a bucket."""
+    from repro.serving.kv_transfer import PaddedRows
+    cfg, api, params = small_model
+    pre = PrefillEngine(cfg, params, max_seq=64)
+    lens = [17, 20, 23, 29]                             # all in bucket 32
+    out = pre.run(_reqs(cfg, lens, 4), backend="ref")
+    wts = [t for _, w, _ in out for s in w.slots.values() for t in s.values()]
+    assert all(isinstance(t.payload, PaddedRows) for t in wts)
+
+    def fill(use_cut):
+        eng = DecodeEngine(cfg, params, max_slots=4, max_seq=64, paged=True,
+                           page_size=8)
+        for r, w, f in out:
+            if use_cut:
+                w = dataclasses.replace(w, slots={
+                    n: {k: dataclasses.replace(t, payload=dict(t.payload))
+                        for k, t in s.items()} for n, s in w.slots.items()})
+            assert admit_one(eng, r, f, wire=w, backend="ref")
+        return eng
+
+    n = []
+
+    def count(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            n.append(duration)
+    fill(False)                                 # warm the first length
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        whole = fill(False)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert n == [], f"{len(n)} compiles for prompt lengths of one bucket"
+    cut = fill(True)
+    assert whole.zero_copy_inserts == cut.zero_copy_inserts == 2 * len(lens)
+    for x, y in zip(jax.tree.leaves(whole.cache),
+                    jax.tree.leaves(cut.cache)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_paged_bf16_resident_decodes(small_model):

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a TPU
 v5e that is described, not attached, at gemma-2b's published widths (and
-the paged decode kernel at the benchmark's Mistral-7B engine shapes too).
+the paged decode kernel at the benchmark's Mistral-7B and Command-R engine
+shapes too, with Command-R's whole decode chunk at its engine's sizes).
 
 Interpret mode accepts casts and reshapes that the chip's compiler
 (Mosaic) refuses, so the kernel tests elsewhere cannot catch those. Each
@@ -8,13 +9,16 @@ compile here is what the chip would compile, and fails the same way. The
 topology is described inside a fixture, never at import: only one process
 may load the TPU compiler library.
 """
+import json
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import model as bench_model
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models import build, paged
@@ -71,12 +75,14 @@ def test_kv_dequant_compiles(spec):
 
 # (Hkv, gq, hd, slots, table width, pages, ppr) of a decode engine's pool:
 # gemma-2b as above; the benchmark's mistral-7b-l16 engine (32 slots of
-# 5120 tokens, 6145 16-token pages, g 128 over 8 KV heads of 128)
+# 5120 tokens, 6145 16-token pages, g 128 over 8 KV heads of 128) and its
+# command-r-32b-l4 engine (64 slots, 8193 pages, 8 query heads a KV head)
 PAGED_SHAPES = {
     "gemma-2b": (CFG.num_kv_heads, CFG.num_heads // CFG.num_kv_heads,
                  CFG.head_dim, SLOTS, paged.table_width(MAX_SEQ, PAGE_SIZE),
                  PAGES, PPR),
     "mistral-7b-l16": (8, 4, 128, 32, 320, 6145, 8),
+    "command-r-32b-l4": (8, 8, 128, 64, 320, 8193, 8),
 }
 
 
@@ -115,6 +121,38 @@ def test_paged_decode_step_compiles_and_fits(spec):
     compiled = jax.jit(step).lower(
         as_spec(params), as_spec(cache),
         {"tokens": spec((SLOTS, 1), jnp.int32)}).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, used
+
+
+def test_command_r_decode_chunk_compiles_and_fits(spec):
+    """``command-r-32b-l4``'s 64-slot, 8-step paged int4 decode chunk at
+    its engine's page count, as the decode engine jits it: the weights
+    (10.23 GB), the pool and the chunk's temporaries fit the chip (14.50 GB
+    at this writing; the configuration's ``sizing``)."""
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                      / "configs" / "command-r-32b-l4.json").read_text())
+    eng = cfg["engine"]
+    mcfg = bench_model.model_config(cfg)
+    params = jax.eval_shape(lambda k: bench_model.make_params(cfg, k),
+                            jax.random.key(0))
+    B = eng["max_slots"]
+    cache = jax.eval_shape(lambda: paged.init_paged_cache(
+        mcfg, B, eng["decode_max_seq"], eng["num_pages"],
+        page_size=eng["page_size"]))
+    state = {"cur": (B,), "active": (B,), "n_out": (B,), "max_new": (B,)}
+    state = {k: spec(v, jnp.bool_ if k == "active" else jnp.int32)
+             for k, v in state.items()}
+    as_spec = lambda t: jax.tree.map(lambda a: spec(a.shape, a.dtype), t)
+    _, chunk = build(mcfg).paged_decode_fns(eng["page_size"], "pallas")
+    compiled = jax.jit(chunk, static_argnames=(
+        "n_steps", "eos_id", "max_seq")).lower(
+        as_spec(params), as_spec(cache), state,
+        n_steps=eng["decode_chunk_steps"], eos_id=-1,
+        max_seq=eng["decode_max_seq"]).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes

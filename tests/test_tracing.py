@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 from jax.profiler import TraceAnnotation
 
+from bench.program_trace import hlo_scopes
 from conftest import admit_many
 from repro.configs import get_reduced
 from repro.kernels import paged_attention
@@ -195,3 +196,31 @@ def test_decode_ops_sit_under_named_scopes(small_model):
     text = pre._prefill_fn((1, 16)).lower(params, batch).compile().as_text()
     assert text.startswith("HloModule jit__lambda")
     assert "/prefill/" in text
+
+
+@pytest.mark.parametrize("path", ["prefill", "prefill_suffix"])
+def test_prefill_ops_sit_under_named_scopes(path):
+    """A parallel-block prefill (Command-R's kind, tiny): the projections,
+    attention, the MLP beside it and the head each under their scope, as
+    the benchmark's trace reader names scopes (JAX's loop wrappers
+    dropped)."""
+    cfg = get_reduced("command-r-35b")
+    params = build(cfg).init(KEY)
+    pre = PrefillEngine(cfg, params, max_seq=64)
+    batch = {"tokens": np.zeros((1, 16), np.int32),
+             "last_pos": np.zeros((1,), np.int32),
+             "true_len": np.ones((1,), np.int32)}
+    if path == "prefill":
+        fn = pre._prefill_fn((1, 16))
+    else:
+        kv = np.zeros((cfg.num_layers, 1, 8, cfg.num_kv_heads,
+                       cfg.head_dim), np.float32)
+        batch.update(prefix_kv={"slot0": (kv, kv)},
+                     prefix_len=np.full((1,), 8, np.int32))
+        fn = pre._suffix_fn((1, 16, 8))
+    text = fn.lower(params, batch).compile().as_text()
+    assert text.startswith("HloModule jit__lambda")
+    scopes = set(hlo_scopes(text).values())
+    for scope in ("prefill/qkv", "prefill/attn", "prefill/ffn",
+                  "prefill/head"):
+        assert scope in scopes, scope
