@@ -97,21 +97,24 @@ def kernel_parity(cfg, seed):
           f"(limit {TOL_DEQUANT})")
     ok &= rel <= TOL_DEQUANT
 
-    # int4 paged attention, ragged lengths, pages scattered over the pool
+    # int4 paged attention, ragged lengths, pages scattered over the pool,
+    # read at the second layer of a two-layer stacked pool
     W = paged.table_width(MAX_SEQ, PAGE_SIZE)
     P = MAX_SLOTS * W + 1
     R = PAGE_SIZE * ppr
-    rows = jax.random.normal(k2, (2 * P * R, g), jnp.float32)
+    rows = jax.random.normal(k2, (2 * 2 * P * R, g), jnp.float32)
     kq, ks, kz = ops.kv_quant(rows, backend="ref")
-    (kp, vp), (kss, vss), (kzz, vzz) = (a.reshape(2, P, R, -1)
-                                        for a in (kq, ks, kz))
+    kq = kq.reshape(2, 2, P, R, -1)                  # (K/V, layer, ...)
+    ks, kz = (a.reshape(2, 2, P, R) for a in (ks, kz))
+    pages = {"kp": kq[0], "vp": kq[1],
+             "sz": jnp.stack([ks[0], kz[0], ks[1], kz[1]], axis=2)}
     perm = np.asarray(jax.random.permutation(k3, np.arange(1, P)))
     table = jnp.asarray(perm.reshape(MAX_SLOTS, W).astype(np.int32))
     lens = jnp.asarray([MAX_SEQ, MAX_SEQ // 2 - 24, 17, 1], jnp.int32)
     q = jax.random.normal(k4, (MAX_SLOTS, Hkv, gq, hd), jnp.float32)
-    outs = [ops.paged_decode_attention(q, (kp, kss, kzz), (vp, vss, vzz),
-                                       table, lens, page_size=PAGE_SIZE,
-                                       backend=b) for b in ("pallas", "ref")]
+    outs = [ops.paged_decode_attention(q, pages, table, lens, 1,
+                                       page_size=PAGE_SIZE, backend=b)
+            for b in ("pallas", "ref")]
     err = float(jnp.max(jnp.abs(outs[0] - outs[1])))
     r = rel_l2(outs[0], outs[1])
     print(f"  paged attention (int4, {P} pages, lengths "

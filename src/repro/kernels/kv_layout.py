@@ -82,19 +82,22 @@ def interleave_nibbles(packed):
         packed.shape[:-1] + (packed.shape[-1] * 2,))
 
 
-def interleave_nibbles_mxu(packed):
-    """:func:`interleave_nibbles` of a 2-D ``(n, G//2)`` plane as two
-    matmuls, for Pallas TPU kernel bodies: Mosaic builds the stack-and-
-    reshape interleave out of 2-lane tiles, many times the block's VMEM.
-    Exact: the nibbles (0..15) and the 0/1 placement matrices are exact in
-    bf16, and each output element sums one product in f32."""
-    lo, hi = unpack_nibbles(packed)
-    half = packed.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+def interleave_nibbles_mxu_t(packed_t):
+    """:func:`interleave_nibbles` down the sublanes, as two matmuls: a
+    ``(G//2, n)`` plane whose columns are the packed rows -> ``(G, n)``
+    f32 whose column ``c`` is row ``c`` interleaved, for Pallas TPU
+    kernel bodies that read packed rows lane-dense (Mosaic builds a
+    stack-and-reshape interleave out of 2-lane tiles, many times the
+    block's VMEM). Exact: the nibbles (0..15) and the 0/1 placement
+    matrices are exact in bf16, and each output element sums one product
+    in f32."""
+    lo, hi = unpack_nibbles(packed_t)
+    half = packed_t.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (2 * half, half), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (2 * half, half), 1)
 
     def place(plane, odd):
-        return jnp.dot(plane.astype(jnp.bfloat16),
-                       (col == 2 * row + odd).astype(jnp.bfloat16),
+        return jnp.dot((row == 2 * col + odd).astype(jnp.bfloat16),
+                       plane.astype(jnp.bfloat16),
                        preferred_element_type=jnp.float32)
     return place(lo, 0) + place(hi, 1)

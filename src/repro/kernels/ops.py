@@ -53,19 +53,18 @@ def decode_attention(q, k, v, kv_len, *, backend="auto", block_k=256):
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "page_size"))
-def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
+def paged_decode_attention(q, pages, page_table, kv_len, layer, *,
                            page_size, backend="auto"):
     """Decode attention over a page-table-indirected KV cache; the int4
     residency dequantizes INSIDE the kernel (never materializing a 16-bit
-    cache). ``k_pages``/``v_pages``: (packed, scale, zero) triple or a
-    dense (P, page_size, Hkv, hd) array."""
+    cache). ``pages``: one attention slot's stacked pool, int4 ``{"kp",
+    "vp", "sz"}`` or bf16 ``{"k", "v"}``, read at ``layer``."""
     m = _mode(backend)
     if m == "ref":
-        return _ref.paged_decode_attention_ref(q, k_pages, v_pages,
-                                               page_table, kv_len,
-                                               page_size=page_size)
-    return _pa.paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      kv_len, page_size=page_size,
+        return _ref.paged_decode_attention_ref(q, pages, page_table, kv_len,
+                                               layer, page_size=page_size)
+    return _pa.paged_decode_attention(q, pages, page_table, kv_len, layer,
+                                      page_size=page_size,
                                       interpret=(m == "interpret"))
 
 

@@ -11,12 +11,26 @@ sequence's pages are named by a per-slot page table. Two residencies:
   cache is never materialized in 16-bit.
 * **bf16**: pages store plain 16-bit values (ablation / fallback).
 
-Page layout (int4), per layer: ``packed (P, page_size*ppr, g//2) u8`` with
-``scale``/``zero (P, page_size*ppr, 1) f32``, where ``g`` is the wire's
-position-aligned quantization group (g | Hkv*hd) and ``ppr = Hkv*hd // g``
-groups per token. Row ``t*ppr + r`` holds token ``t``'s r-th group, so a
+Page layout (int4), stacked over the model's ``n_super`` layers:
+``kp``/``vp (n_super, P, page_size*ppr, g//2) u8`` packed nibbles and one
+``sz (n_super, P, 4, page_size*ppr) f32`` holding, per page row, K's
+scale, K's zero, V's scale and V's zero (rows ``SZ_ROWS`` of
+``models/paged.py``), lane-dense. ``g`` is the wire's position-aligned
+quantization group (g | Hkv*hd) and ``ppr = Hkv*hd // g`` groups per
+token. Row ``t*ppr + r`` of a page holds token ``t``'s r-th group, so a
 page row-range is exactly a token range — the same row order the wire's
-flattened ``(L*len*ppr, g)`` quantization produces.
+flattened ``(L*len*ppr, g)`` quantization produces. The kernel takes the
+stacked arrays whole, with the layer as a scalar-prefetch operand: each
+page operand's block is ``(1, 1, ...)`` at ``(layer, page, 0, ...)`` and
+its trailing dims are the array's, so the layout the pool is carried in
+is the one the kernel reads, and nothing copies a layer's pool out
+before the call. The operands are held to HBM, so XLA does not stage a
+pool small enough for VMEM through it around each launch. It reads the
+packed pages as ``(g//2, page_size*ppr)``, rows on lanes: where a page's
+rows fill whole 128-lane tiles, that is how a TPU lays ``kp``/``vp``
+out, and the transpose is a bitcast. The nibbles then interleave down
+the sublanes and each row's scale and zero apply from their lanes, with
+no transpose of ``sz``.
 
 The grid is ragged: one step per block of ``ppb`` pages that holds
 context, summed over the slots (at least one a slot), so a launch's cost
@@ -50,23 +64,45 @@ BLOCK_TOKENS = 128
 BLOCK_VMEM_BYTES = 8 << 20
 
 
+# a residency's pool operands, as the cache names them
+INT4_OPERANDS = ("kp", "vp", "sz")
+BF16_OPERANDS = ("k", "v")
+
+
+def _keys_as_columns(g, hd, rows):
+    """Whether an int4 block's keys stay as its dequantized columns:
+    where a group is one head, page row ``t*Hkv + h`` is head ``h`` of
+    token ``t``, and a block of whole 128-lane pages needs no transpose."""
+    return g == hd and rows % 128 == 0
+
+
 def _load_int4(pages, *, tokens, Hkv, hd):
-    """Dequantize a block: its pages' rows stacked, (ppb*ps*ppr, g//2) u8
-    and (ppb*ps*ppr, 1) scale/zero -> (ppb*ps*ppr, g) f32, token-major, so
-    each token's flattened Hkv*hd slice -> (tokens, Hkv, hd). Nibble order
-    comes from the shared layout contract (``kernels/kv_layout.py``, rule
-    R005): element 2j in the low nibble of byte j, 2j+1 high."""
-    def dequant(packed, scale, zero):
-        rows = lambda refs: jnp.concatenate([r[0] for r in refs])
-        x = kv_layout.interleave_nibbles_mxu(rows(packed))
-        return (x * rows(scale) + rows(zero)).reshape(tokens, Hkv, hd)
-    kp, ks, kz, vp, vs, vz = pages
-    return dequant(kp, ks, kz), dequant(vp, vs, vz)
+    """Dequantize a block. Its packed pages arrive as ``(g//2, ps*ppr)``
+    (the pool's lane-dense layout, rows on lanes); the nibbles interleave
+    down the sublanes to ``(g, rows)`` f32 and each row's scale and zero,
+    a lane of the ``(4, rows)`` ``sz`` block, apply as ``x * scale +
+    zero``. Where a group is one head (``_keys_as_columns``) the block's
+    keys stay as these ``(hd, tokens*Hkv)`` columns; otherwise each page
+    transposes to rows and the block reshapes to ``(tokens*Hkv, hd)``.
+    Nibble order comes from the shared layout contract
+    (``kernels/kv_layout.py``, rule R005): element 2j in the low nibble of
+    byte j, 2j+1 high."""
+    kp, vp, sz = pages
+    g, R = 2 * kp[0].shape[2], kp[0].shape[3]
+    cols = _keys_as_columns(g, hd, R)
+
+    def dequant(packed, c):
+        xs = [kv_layout.interleave_nibbles_mxu_t(p[0, 0]) * z[0, 0, c:c + 1]
+              + z[0, 0, c + 1:c + 2] for p, z in zip(packed, sz)]
+        if cols:
+            return jnp.concatenate(xs, axis=1)
+        return jnp.concatenate([x.T for x in xs]).reshape(tokens * Hkv, hd)
+    return cols, dequant(kp, 0), dequant(vp, 2)
 
 
 def _load_bf16(pages, *, tokens, Hkv, hd):
-    return tuple(jnp.concatenate([r[0] for r in refs]).astype(jnp.float32)
-                 for refs in pages)
+    return False, *(jnp.concatenate([r[0, 0] for r in refs]).astype(
+        jnp.float32).reshape(tokens * Hkv, hd) for refs in pages)
 
 
 def _limit(kv_len, page_size, W):
@@ -79,8 +115,12 @@ def _n_blocks(limit, page_size, ppb):
     return jnp.maximum(pl.cdiv(pl.cdiv(limit, page_size), ppb), 1)
 
 
-def _kernel(slot_ref, blk_ref, page_ref, len_ref, q_ref, *refs, load, n_ops,
-            sm_scale, page_size, ppb, W):
+def _kernel(slot_ref, blk_ref, page_ref, len_ref, layer_ref, q_ref, *refs,
+            load, n_ops, sm_scale, page_size, ppb, W, Hkv):
+    """One block of one slot. Each of the ``M = Hkv*gq`` query rows
+    scores every key of the block, ``n = t*Hkv + h``, and keeps those of
+    its own KV head that hold context: where the block's keys stay as
+    columns that is one plain matmul and no transpose."""
     pages = [refs[n * ppb:(n + 1) * ppb] for n in range(n_ops)]
     o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
     t = pl.program_id(0)
@@ -93,35 +133,38 @@ def _kernel(slot_ref, blk_ref, page_ref, len_ref, q_ref, *refs, load, n_ops,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)            # (Hkv, gq, hd)
-    Hkv, gq, hd = q.shape
+    q = q_ref[0].astype(jnp.float32)            # (M, hd), m = h*gq + i
+    M, hd = q.shape
     tokens = ppb * page_size
-    k, v = load(pages, tokens=tokens, Hkv=Hkv, hd=hd)
+    cols, k, v = load(pages, tokens=tokens, Hkv=Hkv, hd=hd)
+    N = tokens * Hkv
     start = j * tokens
     # a page past the context holds whatever its operand held before:
     # zero its values so nothing reaches the sum through p == 0
-    tok = start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-    v = jnp.where(tok < limit, v, 0.0)
-    s = jax.lax.dot_general(q, k.transpose(1, 0, 2),
-                            (((2,), (2,)), ((0,), (0,))),
+    key_axis = 1 if cols else 0
+    v_tok = start + jax.lax.broadcasted_iota(jnp.int32, v.shape,
+                                             key_axis) // Hkv
+    v = jnp.where(v_tok < limit, v, 0.0)
+    s = jax.lax.dot_general(q, k, (((1,), (0 if cols else 1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
-    pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(pos < limit, s, NEG_INF)      # (Hkv, gq, tokens)
+    n = jax.lax.broadcasted_iota(jnp.int32, (M, N), 1)
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0) // (M // Hkv)
+    s = jnp.where((n % Hkv == row_head) & (start + n // Hkv < limit), s,
+                  NEG_INF)
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[..., None])
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[..., None] + jax.lax.dot_general(
-        p, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (1 if cols else 0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(j == _n_blocks(limit, page_size, ppb) - 1)
     def _done():
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l_scr[...], 1e-30)[..., None]).astype(
-                        o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+            o_ref.dtype)
 
 
 def _schedule(page_table, kv_len, *, page_size, ppb):
@@ -181,53 +224,65 @@ def grid_pages(kv_len, page_size: int) -> int:
     return sum(-(-int(n) // page_size) for n in kv_len)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
+def paged_decode_attention(q, pages, page_table, kv_len, layer, *,
                            page_size, sm_scale=None, interpret=False):
     """q: (B, Hkv, gq, hd); page_table: (B, W) int32 (entry 0 = the trash
-    page, see ``serving/page_pool.py``); kv_len: (B,) valid lengths.
+    page, see ``serving/page_pool.py``); kv_len: (B,) valid lengths;
+    layer: the index into the pool's stacked layer axis.
 
-    ``k_pages``/``v_pages`` are either the int4 triple
-    ``(packed (P, page_size*ppr, g//2), scale (P, ..., 1), zero)`` or a
-    dense ``(P, page_size, Hkv, hd)`` array. Returns (B, Hkv, gq, hd).
+    ``pages`` is one attention slot's stacked pool as the cache holds it:
+    int4 ``{"kp", "vp", "sz"}`` (the layout above) or bf16 ``{"k", "v"}``
+    of ``(n_super, P, page_size, Hkv, hd)``. Returns (B, Hkv, gq, hd).
     """
     B, Hkv, gq, hd = q.shape
+    M = Hkv * gq
     W = page_table.shape[1]
     sm_scale = sm_scale or 1.0 / math.sqrt(hd)
-    if isinstance(k_pages, (tuple, list)):
-        operands, load = (*k_pages, *v_pages), _load_int4
+    if "kp" in pages:
+        names, load = INT4_OPERANDS, _load_int4
     else:
-        operands, load = (k_pages, v_pages), _load_bf16
+        names, load = BF16_OPERANDS, _load_bf16
+    # the packed pages as the kernel reads them, rows on lanes: on a TPU
+    # the pool's own layout when a page's rows fill whole 128-lane tiles
+    operands = [jnp.swapaxes(pages[k], 2, 3) if k in ("kp", "vp")
+                else pages[k] for k in names]
     ppb = pages_per_block(page_size, W,
-                          [((1,) + a.shape[1:], a.dtype) for a in operands])
+                          [((1, 1) + a.shape[2:], a.dtype) for a in operands])
     n_steps, slot, blk, page = _schedule(
         page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
         page_size=page_size, ppb=ppb)
     kernel = functools.partial(_kernel, load=load, n_ops=len(operands),
                                sm_scale=sm_scale, page_size=page_size,
-                               ppb=ppb, W=W)
+                               ppb=ppb, W=W, Hkv=Hkv)
 
     n = slot.shape[0]
+    if not interpret:
+        # the pool stays in HBM: left free, XLA stages a pool that fits
+        # VMEM (Command-R's ``sz``) through it around every launch
+        operands = [pltpu.with_memory_space_constraint(a, pltpu.HBM)
+                    for a in operands]
 
     def page_spec(a, i):
-        return pl.BlockSpec((1,) + a.shape[1:],
-                            lambda t, s, j, pg, ln: (pg[i * n + t],)
-                            + (0,) * (a.ndim - 1))
+        return pl.BlockSpec((1, 1) + a.shape[2:],
+                            lambda t, s, j, pg, ln, lay: (
+                                lay[0], pg[i * n + t]) + (0,) * (a.ndim - 2))
 
-    q_spec = pl.BlockSpec((1, Hkv, gq, hd),
-                          lambda t, s, *_: (s[t], 0, 0, 0))
+    q_spec = pl.BlockSpec((1, M, hd), lambda t, s, *_: (s[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(n_steps,),
         in_specs=[q_spec] + [page_spec(a, i) for a in operands
                              for i in range(ppb)],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((Hkv, gq), jnp.float32),
-                        pltpu.VMEM((Hkv, gq), jnp.float32),
-                        pltpu.VMEM((Hkv, gq, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((M, 1), jnp.float32),
+                        pltpu.VMEM((M, 1), jnp.float32),
+                        pltpu.VMEM((M, hd), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, gq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, M, hd), q.dtype),
         interpret=interpret,
-    )(slot, blk, page, kv_len.astype(jnp.int32), q,
+    )(slot, blk, page, kv_len.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(B, M, hd),
       *[a for a in operands for _ in range(ppb)])
+    return out.reshape(B, Hkv, gq, hd)

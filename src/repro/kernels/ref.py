@@ -47,34 +47,46 @@ def decode_attention_ref(q, k, v, *, kv_len=None, sm_scale=None):
 
 
 def paged_gather_ref(pages, page_table, *, page_size):
-    """Materialize paged KV into a dense (B, W*page_size, Hkv, hd) f32
-    tensor by gathering each sequence's pages through its page table.
+    """Materialize one layer's paged K and V into dense (B, W*page_size,
+    Hkv*hd) f32 tensors by gathering each sequence's pages through its
+    page table.
 
-    ``pages`` is either the int4 triple ``(packed (P, page_size*ppr, g//2),
-    scale, zero)`` — dequantized here — or a dense (P, page_size, Hkv, hd)
-    array. The oracle for the fused-dequant Pallas kernel."""
+    ``pages`` is one layer's pool: int4 ``{"kp", "vp" (P, page_size*ppr,
+    g//2) u8, "sz" (P, 4, page_size*ppr) f32}`` (rows: K's scale and zero,
+    V's scale and zero) — dequantized here — or bf16 ``{"k", "v" (P,
+    page_size, Hkv, hd)}``. The oracle for the fused-dequant Pallas
+    kernel."""
     pt = jnp.maximum(page_table, 0)               # 0 = trash page
-    if isinstance(pages, (tuple, list)):
-        packed, scale, zero = pages
-        g = packed.shape[-1] * 2
-        x = kv_dequant_ref(packed[pt], scale[pt], zero[pt], dtype=jnp.float32)
-        B, W, R = x.shape[:3]
-        ps = page_size
-        ppr = R // ps
-        return x.reshape(B, W * ps, ppr * g)      # (B, S, Hkv*hd) flat
-    gathered = pages[pt]                          # (B, W, ps, Hkv, hd)
-    B, W, ps, Hkv, hd = gathered.shape
-    return gathered.astype(jnp.float32).reshape(B, W * ps, Hkv * hd)
+    if "kp" in pages:
+        sz = pages["sz"][pt]                      # (B, W, 4, R)
+        out = []
+        for packed, col in ((pages["kp"], 0), (pages["vp"], 2)):
+            x = kv_dequant_ref(packed[pt], sz[:, :, col, :, None],
+                               sz[:, :, col + 1, :, None],
+                               dtype=jnp.float32)  # (B, W, R, g)
+            B, W, R, g = x.shape
+            out.append(x.reshape(B, W * page_size, R // page_size * g))
+        return tuple(out)
+    out = []
+    for a in (pages["k"], pages["v"]):
+        gathered = a[pt]                          # (B, W, ps, Hkv, hd)
+        B, W, ps, Hkv, hd = gathered.shape
+        out.append(gathered.astype(jnp.float32).reshape(B, W * ps,
+                                                        Hkv * hd))
+    return tuple(out)
 
 
-def paged_decode_attention_ref(q, k_pages, v_pages, page_table, kv_len, *,
+def paged_decode_attention_ref(q, pages, page_table, kv_len, layer, *,
                                page_size, sm_scale=None):
-    """q: (B, Hkv, gq, hd); paged K/V as in ``paged_gather_ref``.
+    """q: (B, Hkv, gq, hd); ``pages`` the stacked pool the kernel takes,
+    read at ``layer``.
 
-    Gathers + dequantizes to dense, then runs the dense decode oracle."""
+    Gathers + dequantizes that layer to dense, then runs the dense decode
+    oracle."""
     B, Hkv, gq, hd = q.shape
-    k = paged_gather_ref(k_pages, page_table, page_size=page_size)
-    v = paged_gather_ref(v_pages, page_table, page_size=page_size)
+    pages = {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+             for k, a in pages.items()}
+    k, v = paged_gather_ref(pages, page_table, page_size=page_size)
     S = k.shape[1]
     k = k.reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)

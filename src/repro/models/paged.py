@@ -10,8 +10,10 @@ round-trip, and attention dequantizes inside the kernel
 
 Layout, per attention layer slot (stacked over ``n_super`` superblocks):
 
-* int4: ``kp/vp (n_super, P, page_size*ppr, g//2) u8`` packed nibbles,
-  ``ks/vs`` / ``kz/vz (n_super, P, page_size*ppr, 1) f32`` scale/zero.
+* int4: ``kp/vp (n_super, P, page_size*ppr, g//2) u8`` packed nibbles and
+  ``sz (n_super, P, 4, page_size*ppr) f32``: per page row, K's scale,
+  K's zero, V's scale and V's zero (``SZ_ROWS``), lane-dense, so the
+  layout the decode loop carries is the one the paged kernel reads.
   ``g = page_group(cfg)`` is the wire's position-aligned quantization
   group (g | Hkv*hd); ``ppr = Hkv*hd // g`` groups per token. Row
   ``t*ppr + r`` of a page is token ``t``'s r-th group — identical row
@@ -30,9 +32,11 @@ never hands page 0 out.
 page, attention via ``ops.paged_decode_attention``. Its device work sits
 under named scopes (operation metadata only): ``decode/weights`` (the
 layer's slice of the stacked weights), ``decode/qkv``,
-``decode/kv_append`` (the tail-page write), ``decode/kv_pages`` (each
-layer's pool handed to the kernel), ``decode/paged_attn`` (the kernel and
-the output projection), ``decode/ffn`` and ``decode/head``.
+``decode/kv_append`` (the tail-page write), ``decode/paged_attn`` (the
+kernel, which reads the stacked pool at the loop's layer, and the output
+projection), ``decode/ffn`` and ``decode/head``. Between a chunk's entry
+and its exit the pool moves only where a token is appended and where the
+kernel copies a live page in.
 """
 from __future__ import annotations
 
@@ -52,6 +56,9 @@ from repro.models.transformer import (_apply_ffn, _embed_inputs, head_logits,
 from repro.kernels.kv_layout import pick_group
 
 DEFAULT_PAGE_SIZE = 16
+# rows of a page's ``sz`` array, by the wire's names: K's scale and zero,
+# then V's
+SZ_ROWS = {"k": (0, 1), "v": (2, 3)}
 
 
 def page_group(cfg) -> int:
@@ -99,11 +106,8 @@ def init_paged_cache(cfg, max_slots: int, max_seq: int, num_pages: int, *,
             R = ps * ppr
             cache[f"slot{s}"] = {
                 "kp": jnp.zeros((n_super, num_pages, R, g // 2), jnp.uint8),
-                "ks": jnp.zeros((n_super, num_pages, R, 1), jnp.float32),
-                "kz": jnp.zeros((n_super, num_pages, R, 1), jnp.float32),
                 "vp": jnp.zeros((n_super, num_pages, R, g // 2), jnp.uint8),
-                "vs": jnp.zeros((n_super, num_pages, R, 1), jnp.float32),
-                "vz": jnp.zeros((n_super, num_pages, R, 1), jnp.float32),
+                "sz": jnp.zeros((n_super, num_pages, 4, R), jnp.float32),
             }
         elif resident == "bf16":
             cache[f"slot{s}"] = {
@@ -174,36 +178,28 @@ def decode_step_paged(cfg, params, cache, tokens, *, rt=None,
                     vq, vsc, vzp = kv_quant_ref(v[:, 0].reshape(B * ppr, g))
                     rows = tail_off[:, None] * ppr + jnp.arange(ppr)[None]
                     pg = tail_page[:, None]
-                    for name, val, width in (
-                            ("kp", kq, g // 2), ("ks", ksc, 1),
-                            ("kz", kzp, 1), ("vp", vq, g // 2),
-                            ("vs", vsc, 1), ("vz", vzp, 1)):
+                    for name, val in (("kp", kq), ("vp", vq)):
                         slot[name] = slot[name].at[i, pg, rows].set(
-                            val.reshape(B, ppr, width).astype(
-                                slot[name].dtype))
-                with jax.named_scope("decode/kv_pages"):
-                    kpages = tuple(lax.dynamic_index_in_dim(
-                        slot[n], i, 0, keepdims=False)
-                        for n in ("kp", "ks", "kz"))
-                    vpages = tuple(lax.dynamic_index_in_dim(
-                        slot[n], i, 0, keepdims=False)
-                        for n in ("vp", "vs", "vz"))
+                            val.reshape(B, ppr, g // 2))
+                    # ``sz`` is rewritten a whole tail page at a time:
+                    # XLA keeps that scatter in HBM, where one of single
+                    # lanes has it stage a pool that fits VMEM through it
+                    sz = jnp.stack([ksc, kzp, vsc, vzp], axis=-1)
+                    tile = slot["sz"][i, tail_page]          # (B, 4, R)
+                    tile = tile.at[jnp.arange(B)[:, None], :, rows].set(
+                        sz.reshape(B, ppr, 4))
+                    slot["sz"] = slot["sz"].at[i, tail_page].set(tile)
             else:
                 with jax.named_scope("decode/kv_append"):
                     slot["k"] = slot["k"].at[i, tail_page, tail_off].set(
                         k[:, 0].astype(slot["k"].dtype))
                     slot["v"] = slot["v"].at[i, tail_page, tail_off].set(
                         v[:, 0].astype(slot["v"].dtype))
-                with jax.named_scope("decode/kv_pages"):
-                    kpages = lax.dynamic_index_in_dim(slot["k"], i, 0,
-                                                      keepdims=False)
-                    vpages = lax.dynamic_index_in_dim(slot["v"], i, 0,
-                                                      keepdims=False)
             bc[f"slot{s}"] = slot
             with jax.named_scope("decode/paged_attn"):
                 qr = q[:, 0].reshape(B, Hkv, gq, hd)
-                o = ops.paged_decode_attention(qr, kpages, vpages, pt,
-                                               pos0 + 1, page_size=ps,
+                o = ops.paged_decode_attention(qr, slot, pt, pos0 + 1, i,
+                                               page_size=ps,
                                                backend=backend)
                 out = dense(p["attn"]["wo"],
                             o.reshape(B, 1, cfg.q_dim).astype(x.dtype))

@@ -432,6 +432,17 @@ class PrefillEngine:
         return [(r, wires[i], int(first[i])) for i, r in enumerate(group)]
 
 
+def paged_decode_jits(api, page_size: int, backend: str, *, donate: bool):
+    """A paged decode engine's jitted single step and chunk, with the cache
+    (argument 1) donated when ``donate``."""
+    step_fn, chunk_fn = api.paged_decode_fns(page_size, backend)
+    donated = (1,) if donate else ()
+    return (jax.jit(step_fn, donate_argnums=donated),
+            jax.jit(chunk_fn, static_argnames=("n_steps", "eos_id",
+                                               "max_seq"),
+                    donate_argnums=donated))
+
+
 class DecodeEngine:
     """Throughput-oriented: continuous batching over a slotted cache.
 
@@ -451,6 +462,13 @@ class DecodeEngine:
     the pool can actually admit, which is what the gateway's dispatch and
     shedding read. Unsupported archs fall back to the dense path
     (``paged_fallback`` records why).
+
+    On an accelerator a paged engine donates its cache to the decode
+    step and chunk: the chunk's pool output aliases its input, so a step
+    neither copies the pool nor holds two of it. The arrays passed in are
+    dead afterwards; everything here reads ``self.cache`` afresh. On the
+    CPU it does not donate: memory does not bound the pool there, and
+    tests and fault injection keep a cache from before a step.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 8,
@@ -489,11 +507,9 @@ class DecodeEngine:
             self.cache = paged_fmt.init_paged_cache(
                 cfg, max_slots, max_seq, num_pages, page_size=page_size,
                 resident=kv_resident)
-            step_fn, chunk_fn = self.api.paged_decode_fns(page_size,
-                                                          paged_backend)
-            self._decode = jax.jit(step_fn)
-            self._chunk = jax.jit(
-                chunk_fn, static_argnames=("n_steps", "eos_id", "max_seq"))
+            self._decode, self._chunk = paged_decode_jits(
+                self.api, page_size, paged_backend,
+                donate=jax.default_backend() != "cpu")
             self._slot_pages: Dict[int, List[int]] = {}
             self._need_sum = 0      # pages reserved across admissions
             self._need_n = 0

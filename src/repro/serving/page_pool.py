@@ -9,7 +9,9 @@ Key property (the reason the wire and the pages share one quantization
 layout): a wire produced by the bucketed-prefill fast path
 (``kv_transfer.extract_batch(pad_to=...)``) carries POSITION-ALIGNED int4
 groups — row ``t*ppr + r`` is token ``t``'s r-th group, exactly a page's
-row order — so insertion is a pure uint8/f32 scatter: **no dequantization,
+row order — so insertion is a pure uint8/f32 scatter (packed rows into
+``kp``/``vp``, each row's scale and zero into its lane of the page's
+``sz`` rows; ``models/paged.py`` has the layout): **no dequantization,
 no requantization, no 16-bit materialization**. Wires with position-
 spanning groups (exact-length extracts pick the group from the flattened
 size) or raw payloads are re-encoded into the page layout on arrival (one
@@ -287,28 +289,39 @@ def _wire_to_rows(wt: WireTensor, cfg, backend: str):
 
 @functools.lru_cache(maxsize=None)
 def _scatter_rows_fn():
-    """The jitted scatter of one wire tensor's (packed, scale, zero) rows,
-    covering ``n_pos`` positions, into a slot's pages. Positions from
+    """The jitted scatter of one layer slot's wire rows into a slot's
+    pages: ``vals`` maps ``"k"``/``"v"`` to (packed, scale, zero) rows
+    covering ``n_pos`` positions; packed rows go to ``kp``/``vp``, scale
+    and zero to their rows of ``sz`` (``paged.SZ_ROWS``). Positions from
     ``ln`` on (a padded bucket's tail) index past the pool and are
     dropped, so ``ln`` is a traced scalar: one program per bucket shape,
     not per prompt length."""
     import jax
     import jax.numpy as jnp
 
-    def scatter(dsts, vals, pages, ln, page_size):
-        L, n_pages, R = dsts[0].shape[:3]
+    from repro.models import paged
+
+    def scatter(pool, vals, pages, ln, page_size):
+        L, n_pages, R = pool["kp"].shape[:3]
         ppr = R // page_size
-        n_pos = vals[0].shape[0] // (L * ppr)
-        tpos = jnp.arange(n_pos)
-        pg = jnp.where(tpos < ln, pages[tpos // page_size],
-                       n_pages)[:, None]                       # (n_pos, 1)
-        rows = ((tpos % page_size)[:, None] * ppr
-                + jnp.arange(ppr)[None])                       # (n_pos, ppr)
-        return tuple(
-            d.at[:, pg, rows].set(
-                v.reshape(L, n_pos, ppr, v.shape[1]).astype(d.dtype),
+        out = dict(pool)
+        for base, (packed, scale, zero) in vals.items():
+            n_pos = packed.shape[0] // (L * ppr)
+            tpos = jnp.arange(n_pos)
+            pg = jnp.where(tpos < ln, pages[tpos // page_size],
+                           n_pages)[:, None]                   # (n_pos, 1)
+            rows = ((tpos % page_size)[:, None] * ppr
+                    + jnp.arange(ppr)[None])                   # (n_pos, ppr)
+            key = base + "p"
+            out[key] = out[key].at[:, pg, rows].set(
+                packed.reshape(L, n_pos, ppr, -1).astype(out[key].dtype),
                 mode="drop")
-            for d, v in zip(dsts, vals))
+            sz = jnp.stack([a.reshape(L, n_pos, ppr) for a in (scale, zero)],
+                           axis=1)                      # (L, 2, n_pos, ppr)
+            which = jnp.asarray(paged.SZ_ROWS[base])[:, None, None]
+            out["sz"] = out["sz"].at[:, pg, which, rows].set(
+                sz.astype(out["sz"].dtype), mode="drop")
+        return out
     return jax.jit(scatter, static_argnames=("page_size",))
 
 
@@ -358,28 +371,25 @@ def _insert_wires(cache, cfg, items: Sequence[Tuple], backend: str):
         dst_page = np.asarray(pages, np.int32)[tpos // ps]          # (ln,)
         for name, slot_wire in wire.slots.items():
             buf = cache[name]
-            for key, base in (("k", "k"), ("v", "v")):
-                wt = slot_wire.get(key)
-                if wt is None:
-                    continue
-                if int4:
-                    vals, n_pos, aligned = _wire_to_rows(wt, cfg, backend)
+            if int4:
+                vals, n_pos = {}, 0
+                for key, wt in slot_wire.items():
+                    rows, n, aligned = _wire_to_rows(wt, cfg, backend)
+                    vals[key] = rows
+                    n_pos = max(n_pos, n)
                     n_zero += int(aligned)
                     n_reenc += int(not aligned)
-                    n_pg = -(-n_pos // ps)
-                    pg = np.zeros((n_pg,), np.int32)
-                    pg[:min(n_pg, len(pages))] = pages[:n_pg]
-                    keys = [base + sfx for sfx in ("p", "s", "z")]
-                    out = _scatter_rows_fn()(
-                        tuple(buf[k] for k in keys), vals, jnp.asarray(pg),
-                        ln, page_size=ps)
-                    for k, a in zip(keys, out):
-                        cache[name][k] = a
-                else:
-                    dense = _dequantize(wt, backend)     # (L, ln, Hkv, hd)
-                    dst = buf[base]
-                    cache[name][base] = dst.at[
-                        :, dst_page, tpos % ps].set(dense.astype(dst.dtype))
+                n_pg = -(-n_pos // ps)
+                pg = np.zeros((n_pg,), np.int32)
+                pg[:min(n_pg, len(pages))] = pages[:n_pg]
+                cache[name] = _scatter_rows_fn()(buf, vals, jnp.asarray(pg),
+                                                 ln, page_size=ps)
+                continue
+            for key, wt in slot_wire.items():
+                dense = _dequantize(wt, backend)         # (L, ln, Hkv, hd)
+                dst = buf[key]
+                cache[name][key] = dst.at[
+                    :, dst_page, tpos % ps].set(dense.astype(dst.dtype))
         row = np.zeros((W,), np.int32)                   # rest -> trash
         chain = prefix + list(pages)
         row[:len(chain)] = chain
@@ -407,9 +417,11 @@ def set_page_chain(cache, slot: int, pages: Sequence[int], length: int):
 
 
 def copy_page(cache, src_page: int, dst_page: int):
-    """Device-copy one page's contents across every layer tensor — the
-    copy-on-write step before a slot appends to a page it shares. Pure
-    data movement; refcount bookkeeping stays in :class:`PagePool`."""
+    """Device-copy one page's contents across every layer tensor (packed
+    rows and the ``sz`` scale/zero lanes alike: the page is axis 1 of
+    each) — the copy-on-write step before a slot appends to a page it
+    shares. Pure data movement; refcount bookkeeping stays in
+    :class:`PagePool`."""
     for name, buf in cache.items():
         if name in ("page_table", "lengths"):
             continue
@@ -424,7 +436,8 @@ def extract_slot_wire(cache, cfg, ln: int, pages: Sequence[int],
     (the decode->decode migration path of ``Gateway.handle_preemption``).
 
     For the int4 residency this is a pure page gather — the pages already
-    hold the wire's position-aligned group encoding, so the produced
+    hold the wire's position-aligned group encoding (packed rows from
+    ``kp``/``vp``, scale and zero from their ``sz`` lanes), so the produced
     tensors pass ``_wire_rows_aligned`` and scatter zero-copy into the
     destination pool (no dequant/requant round-trip in either direction).
     The bf16 residency ships raw tokens. Tokens appended by the in-loop
@@ -449,20 +462,20 @@ def extract_slot_wire(cache, cfg, ln: int, pages: Sequence[int],
         if name in ("page_table", "lengths"):
             continue
         out: Dict[str, WireTensor] = {}
-        for key, base in (("k", "k"), ("v", "v")):
+        for key in ("k", "v"):
             if int4:
-                payload = {}
-                for suffix, wkey, width in (("p", "packed", g // 2),
-                                            ("s", "scale", 1),
-                                            ("z", "zero", 1)):
-                    a = buf[base + suffix][:, pg]        # (L, n_pg, R, w)
-                    L = a.shape[0]
-                    payload[wkey] = a.reshape(
-                        L, len(pages) * ps, ppr, width)[:, :ln].reshape(
-                        -1, width)
+                a = buf[key + "p"][:, pg]                # (L, n_pg, R, g/2)
+                sz = buf["sz"][:, pg]                    # (L, n_pg, 4, R)
+                L = a.shape[0]
+                cut = lambda x, w: x.reshape(
+                    L, len(pages) * ps, ppr, w)[:, :ln].reshape(-1, w)
+                s_row, z_row = paged.SZ_ROWS[key]
+                payload = {"packed": cut(a, g // 2),
+                           "scale": cut(sz[:, :, s_row], 1),
+                           "zero": cut(sz[:, :, z_row], 1)}
                 out[key] = WireTensor("int4", payload, (L, ln, Hkv, hd))
             else:
-                a = buf[base][:, pg]                 # (L, n_pg, ps, Hkv, hd)
+                a = buf[key][:, pg]                  # (L, n_pg, ps, Hkv, hd)
                 L = a.shape[0]
                 t = a.reshape(L, len(pages) * ps, Hkv, hd)[:, :ln]
                 out[key] = WireTensor("raw", {"x": t}, tuple(t.shape),

@@ -22,7 +22,7 @@ from repro.kernels import ops, ref
 from repro.models import build, paged
 from repro.serving import page_pool
 from repro.serving.engine import DecodeEngine, GenRequest, PrefillEngine, \
-    Replica
+    Replica, paged_decode_jits
 from repro.serving.page_pool import PagePool, pages_needed
 
 KEY = jax.random.PRNGKey(0)
@@ -43,10 +43,12 @@ class Lens(list):
 
 
 def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
-                   resident="int4"):
-    """Random dense K/V scattered into a shuffled page pool; returns
-    (q, k_pages, v_pages, page_table, dense_k, dense_v) where dense_* are
-    the values attention should see (dequantized for int4 residency)."""
+                   resident="int4", n_layers=1, layer=0):
+    """Random dense K/V scattered into a shuffled page pool at ``layer`` of
+    an ``n_layers``-deep stacked pool (the other layers hold noise);
+    returns (q, pages, page_table, dense_k, dense_v) where ``pages`` is the
+    pool dict the cache holds and dense_* the values attention should see
+    (dequantized for int4 residency)."""
     rng = np.random.default_rng(seed)
     B_ = len(kv_len)
     assert B_ == B
@@ -87,10 +89,10 @@ def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
                     dense[b, t] = np.asarray(
                         pages[pt[b, t // page_size], t % page_size],
                         np.float32)
-            return pages, dense
+            return (pages,), dense
         kp = np.zeros((P, page_size * ppr, g // 2), np.uint8)
-        ks = np.zeros((P, page_size * ppr, 1), np.float32)
-        kz = np.zeros((P, page_size * ppr, 1), np.float32)
+        ks = np.zeros((P, page_size * ppr), np.float32)
+        kz = np.zeros((P, page_size * ppr), np.float32)
         dense = np.zeros_like(X)
         for b in range(B):
             for t in range(int(kv_len[b])):
@@ -98,16 +100,29 @@ def _paged_fixture(B, Hkv, gq, hd, page_size, kv_len, *, seed=0,
                 p_, s_, z_ = ref.kv_quant_ref(jnp.asarray(rows))
                 page, r0 = pt[b, t // page_size], (t % page_size) * ppr
                 kp[page, r0:r0 + ppr] = np.asarray(p_)
-                ks[page, r0:r0 + ppr] = np.asarray(s_)
-                kz[page, r0:r0 + ppr] = np.asarray(z_)
+                ks[page, r0:r0 + ppr] = np.asarray(s_)[:, 0]
+                kz[page, r0:r0 + ppr] = np.asarray(z_)[:, 0]
                 dense[b, t] = np.asarray(ref.kv_dequant_ref(
                     p_, s_, z_, dtype=jnp.float32)).reshape(Hkv, hd)
-        return (jnp.asarray(kp), jnp.asarray(ks), jnp.asarray(kz)), dense
+        return (kp, ks, kz), dense
 
-    k_pages, dense_k = to_pages(K)
-    v_pages, dense_v = to_pages(V)
+    k_arrays, dense_k = to_pages(K)
+    v_arrays, dense_v = to_pages(V)
+    if resident == "bf16":
+        one = {"k": k_arrays[0], "v": v_arrays[0]}
+    else:
+        (kp, ks, kz), (vp, vs, vz) = k_arrays, v_arrays
+        one = {"kp": kp, "vp": vp, "sz": np.stack([ks, kz, vs, vz], 1)}
+
+    def noise(a):
+        if a.dtype == np.uint8:
+            return rng.integers(0, 256, a.shape).astype(np.uint8)
+        return jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+    pages = {k: jnp.stack([jnp.asarray(a) if l == layer else noise(a)
+                           for l in range(n_layers)])
+             for k, a in one.items()}
     q = jnp.asarray(rng.standard_normal((B, Hkv, gq, hd)), jnp.float32)
-    return q, k_pages, v_pages, jnp.asarray(pt), dense_k, dense_v
+    return q, pages, jnp.asarray(pt), dense_k, dense_v
 
 
 # ragged + page-straddling: lengths deliberately off page boundaries, one
@@ -130,14 +145,17 @@ KERNEL_CASES = [
 @pytest.mark.parametrize("backend", ["ref", "interpret"])
 @pytest.mark.parametrize("B,Hkv,gq,hd,ps,lens", KERNEL_CASES)
 @pytest.mark.parametrize("resident", ["int4", "bf16"])
+@pytest.mark.parametrize("layer", [0, 2], ids=["first_layer", "last_layer"])
 def test_paged_attention_matches_dense_ref(backend, B, Hkv, gq, hd, ps,
-                                           lens, resident):
-    """The fused-dequant paged kernel must equal the dense oracle run over
-    the SAME (dequantized) values — bf16-level tolerance."""
+                                           lens, resident, layer):
+    """The fused-dequant paged kernel, reading one layer of a stacked
+    3-layer pool, must equal the dense oracle run over the SAME
+    (dequantized) values — bf16-level tolerance."""
     kv_len = jnp.asarray(lens, jnp.int32)
-    q, kpg, vpg, pt, dk, dv = _paged_fixture(B, Hkv, gq, hd, ps, lens,
-                                             resident=resident)
-    out = ops.paged_decode_attention(q, kpg, vpg, pt, kv_len,
+    q, pages, pt, dk, dv = _paged_fixture(B, Hkv, gq, hd, ps, lens,
+                                          resident=resident, n_layers=3,
+                                          layer=layer)
+    out = ops.paged_decode_attention(q, pages, pt, kv_len, layer,
                                      page_size=ps, backend=backend)
     want = ref.decode_attention_ref(
         q, jnp.asarray(dk.transpose(0, 2, 1, 3)),
@@ -155,26 +173,33 @@ def test_paged_attention_ignores_trash_and_masked_tail(backend):
     that name pages another slot owns must not leak into the output."""
     lens = [5, 16, 23]
     kv_len = jnp.asarray(lens, jnp.int32)
-    q, kpg, vpg, pt, dk, dv = _paged_fixture(3, 2, 4, 16, 8, lens, seed=4)
+    q, pages, pt, dk, dv = _paged_fixture(3, 2, 4, 16, 8, lens, seed=4,
+                                          n_layers=2, layer=1)
     # slots 0 and 1 keep stale entries past their lengths: slot 2's pages
     pt = pt.at[0, 1:].set(pt[2, 1:]).at[1, 2].set(pt[2, 0])
-    run = lambda k, v: np.asarray(ops.paged_decode_attention(
-        q, k, v, pt, kv_len, page_size=8, backend=backend))
-    out1 = run(kpg, vpg)
-    # poison the trash page, every masked tail row and slot 2's pages
-    ppr = kpg[0].shape[1] // 8
+    run = lambda p: np.asarray(ops.paged_decode_attention(
+        q, p, pt, kv_len, 1, page_size=8, backend=backend))
+    out1 = run(pages)
+    # poison the trash page, every masked tail row and slot 2's pages:
+    # packed bytes 255, scales 1e6 and zeros -1e6
+    ppr = pages["kp"].shape[2] // 8
     tails = [(int(pt[b, l // 8]), (l % 8) * ppr)
              for b, l in enumerate(lens) if l % 8]
+    sz_bad = jnp.asarray([1e6, -1e6, 1e6, -1e6])[:, None]
 
     def poison(pages):
-        out = []
-        for a, bad in zip(pages, (255, 1e6, -1e6)):
-            a = a.at[0].set(bad).at[pt[2]].set(bad)
+        out = {}
+        for k, a in pages.items():
+            bad = sz_bad if k == "sz" else 255
+            a = a.at[1, 0].set(bad).at[1, pt[2]].set(bad)
             for page, row in tails:
-                a = a.at[page, row:].set(bad)
-            out.append(a)
-        return tuple(out)
-    out2 = run(poison(kpg), poison(vpg))
+                if k == "sz":
+                    a = a.at[1, page, :, row:].set(bad)
+                else:
+                    a = a.at[1, page, row:].set(bad)
+            out[k] = a
+        return out
+    out2 = run(poison(pages))
     np.testing.assert_array_equal(out1[:2], out2[:2])
     assert np.isfinite(out2[:2]).all()
 
@@ -440,6 +465,112 @@ def test_padded_wire_inserts_its_cut_rows_with_one_program_a_bucket(
     for x, y in zip(jax.tree.leaves(whole.cache),
                     jax.tree.leaves(cut.cache)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _rel_rms(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+
+def test_zero_copy_insert_then_paged_kernel_matches_dense_decode(
+        small_model):
+    """Bucketed wires scattered zero-copy into the stacked int4 pool, then
+    one decode step through the interpret-mode kernel, give the logits of
+    the dense engine's step over the same wires. They differ only in how
+    KV rests: the dense cache rounds the dequantized prompt KV to bf16 and
+    keeps the new token's K/V exact, where the pool keeps it int4. At
+    this size the two lie 2.7% apart (relative RMS), and the dense step
+    over exact prompt KV (uncompressed wires) 15% from either."""
+    cfg, api, params = small_model
+    pre = PrefillEngine(cfg, params, max_seq=64)
+    out = pre.run(_reqs(cfg, [9, 17, 24], 4), backend="ref")
+    pag = DecodeEngine(cfg, params, max_slots=4, max_seq=64, paged=True,
+                       page_size=8, paged_backend="interpret")
+    den = DecodeEngine(cfg, params, max_slots=4, max_seq=64)
+    for eng in (pag, den):
+        for r, w, f in out:
+            assert admit_one(eng, r, f, wire=w, backend="ref")
+    assert pag.zero_copy_inserts > 0 and pag.reencoded_inserts == 0
+    logits = [eng._decode(params, eng.cache, {
+        "tokens": jnp.asarray(eng.cur_token[:, None])})[0][:3, -1]
+        for eng in (pag, den)]
+    assert _rel_rms(*logits) < 0.06
+
+
+def test_copy_page_and_slot_wire_round_trip_in_the_stacked_format(
+        small_model):
+    """``copy_page`` copies a page's packed rows and its scale/zero lanes
+    in every layer, and a resident slot gathered back into a wire
+    (``extract_slot_wire``) scatters zero-copy into another pool to the
+    same rows, scales and zeros."""
+    cfg, api, params = small_model
+    pre = PrefillEngine(cfg, params, max_seq=64)
+    (r, w, f), = pre.run(_reqs(cfg, [21], 4), backend="ref")
+    src = DecodeEngine(cfg, params, max_slots=2, max_seq=64, paged=True,
+                       page_size=8)
+    assert admit_one(src, r, f, wire=w, backend="ref")
+    src_pages = src._slot_pages[0]
+    wire = page_pool.extract_slot_wire(src.cache, cfg, 21, src_pages)
+    dst = DecodeEngine(cfg, params, max_slots=2, max_seq=64, paged=True,
+                       page_size=8)
+    r2 = GenRequest(7, r.tokens, 4)
+    assert admit_one(dst, r2, f, wire=wire, backend="ref")
+    assert dst.zero_copy_inserts == src.zero_copy_inserts
+    dst_pages = dst._slot_pages[0]
+    n = pages_needed(21, 8)
+    ppr = paged.groups_per_token(cfg)
+    for name in src.cache:
+        if not name.startswith("slot"):
+            continue
+        for key, a in src.cache[name].items():
+            b = dst.cache[name][key]
+            a = np.asarray(a)[:, src_pages[:n]]
+            b = np.asarray(b)[:, dst_pages[:n]]
+            # the last page holds 21 - 16 = 5 tokens: rows past them are
+            # the pool's zeros on both sides
+            np.testing.assert_array_equal(a, b)
+            assert np.abs(a).sum() > 0, (name, key)
+    cache = page_pool.copy_page(dst.cache, dst_pages[0], dst_pages[-1])
+    for key, a in cache["slot0"].items():
+        a = np.asarray(a)
+        np.testing.assert_array_equal(a[:, dst_pages[-1]],
+                                      a[:, dst_pages[0]])
+    assert ppr * 8 == cache["slot0"]["sz"].shape[-1]
+
+
+def test_donated_chunk_leaves_step_insert_and_release_working(small_model):
+    """With the cache donated, as an engine on an accelerator jits its
+    decode, a chunk consumes the arrays it was given and the engine's
+    next step, insert and release go on from the new ones, token for
+    token as an engine that keeps its arrays (the CPU's default)."""
+    cfg, api, params = small_model
+    pre = PrefillEngine(cfg, params, max_seq=64)
+    first = pre.run(_reqs(cfg, [8, 12], 10), backend="ref")
+    later = pre.run(_reqs(cfg, [17], 6, seed=5), backend="ref")
+    toks = []
+    for donate in (True, False):
+        eng = DecodeEngine(cfg, params, max_slots=3, max_seq=64,
+                           chunk_size=4, paged=True, page_size=8)
+        eng._decode, eng._chunk = paged_decode_jits(eng.api, 8, "auto",
+                                                    donate=donate)
+        for r, w, f in first:
+            assert admit_one(eng, dataclasses.replace(r, out_tokens=[]), f,
+                             wire=w, backend="ref")
+        before = eng.cache["slot0"]["kp"]
+        eng.step()
+        assert before.is_deleted() == donate
+        (r, w, f), = later
+        assert admit_one(eng, dataclasses.replace(r, out_tokens=[]), f,
+                         wire=w, backend="ref")
+        eng.step()
+        cut = eng.release(0)
+        done = []
+        while eng.active:
+            done += eng.step()
+        assert eng.pool.n_in_use == 0
+        toks.append(([int(t) for t in cut.out_tokens],
+                     {d.rid: d.out_tokens for d in done}))
+    assert toks[0] == toks[1]
 
 
 def test_paged_bf16_resident_decodes(small_model):

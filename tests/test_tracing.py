@@ -186,8 +186,7 @@ def test_decode_ops_sit_under_named_scopes(small_model):
                             max_seq=32).compile().as_text()
     assert text.startswith("HloModule jit_decode_chunk")
     for scope in ("decode/weights", "decode/qkv", "decode/kv_append",
-                  "decode/kv_pages", "decode/paged_attn", "decode/ffn",
-                  "decode/head"):
+                  "decode/paged_attn", "decode/ffn", "decode/head"):
         assert f"/{scope}/" in text, scope
     pre = PrefillEngine(cfg, params, max_seq=64)
     batch = {"tokens": np.zeros((1, 16), np.int32),
